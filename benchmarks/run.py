@@ -33,6 +33,8 @@ def main() -> None:
     args = ap.parse_args()
     only = args.only.split(",") if args.only else None
     q = args.quick
+    from repro.launch import compile_cache
+    compile_cache.configure()
 
     print("name,us_per_call,derived")
 

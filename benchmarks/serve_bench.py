@@ -68,6 +68,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import knapsack
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.parallel.context import local_context
 from repro.serve import (ContinuousBatchingScheduler, DraftSpec, EngineSpec,
@@ -137,7 +138,7 @@ def _sharded_meta(cfg, params, policy, tokens, prompt_len: int,
         return None
     pol = policy.uniform(4.0)
     pa = jax.tree.map(jnp.asarray, pol.as_arrays())
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     packed = pack_params(params, pol.as_arrays(), cfg)
     engine = ServeEngine(cfg=cfg, params=packed,
                          policy_arrays=pa, ctx=local_context(),

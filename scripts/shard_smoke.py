@@ -41,7 +41,7 @@ def run(arch: str):
             else:
                 fn, args, in_sh, out_sh, meta = dryrun.build_serve_cell(
                     cfg, shape, mesh, ctx, kind)
-            with mesh:
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(fn, in_shardings=in_sh,
                                   out_shardings=out_sh).lower(*args)
                 compiled = lowered.compile()

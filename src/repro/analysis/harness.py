@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.parallel.context import local_context
 from repro.serve import pack_params
@@ -68,10 +69,10 @@ def build_engine(kind: str) -> ServeEngine:
         spec = EngineSpec(**base, prefill_chunk=PREFILL_CHUNK,
                           draft=DraftSpec(kind="ngram", k=DRAFT_K))
     elif kind == "sharded":
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         spec = EngineSpec(**base, mesh=mesh)
     elif kind == "sharded_paged":
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         spec = EngineSpec(**base, mesh=mesh, cache_layout="paged",
                           page_size=PAGE_SIZE)
     else:

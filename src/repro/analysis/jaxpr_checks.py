@@ -28,8 +28,9 @@ def _subjaxprs(v) -> Iterator[Any]:
             yield from _subjaxprs(x)
 
 
-def iter_eqns(jaxpr) -> Iterator[Any]:
-    """Depth-first over every equation, including nested subjaxprs.
+def iter_eqns(jaxpr, opaque: Tuple[str, ...] = ()) -> Iterator[Any]:
+    """Depth-first over every equation, including nested subjaxprs —
+    except the bodies of primitives named in ``opaque``.
 
     Accepts a ClosedJaxpr or an open Jaxpr.
     """
@@ -37,9 +38,11 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name in opaque:
+            continue
         for v in eqn.params.values():
             for sub in _subjaxprs(v):
-                yield from iter_eqns(sub)
+                yield from iter_eqns(sub, opaque)
 
 
 def count_eqns(jaxpr) -> int:
@@ -120,10 +123,11 @@ def find_float_intermediates(closed, min_elems: int,
     alias into the cache check).  Only eqn OUTPUTS count: cache buffers
     legitimately enter full-sized as int8/int4 code arguments, and
     staging buffers enter as full-dtype arguments on the chunked-prefill
-    path.
+    path.  A Pallas kernel body is not entered: its values are one VMEM
+    tile at a time, which is what in-register dequant means.
     """
     out = []
-    for eqn in iter_eqns(closed):
+    for eqn in iter_eqns(closed, opaque=("pallas_call",)):
         for var in eqn.outvars:
             aval = getattr(var, "aval", None)
             if aval is None or not hasattr(aval, "shape"):

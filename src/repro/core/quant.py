@@ -21,7 +21,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def qrange(bits: jax.Array, signed: bool = True) -> Tuple[jax.Array, jax.Array]:
@@ -203,17 +202,16 @@ def pack_codes_kmajor(codes: jax.Array, bits: int) -> jax.Array:
     """
     assert bits in (2, 4), bits
     pack = 8 // bits
-    c = np.asarray(codes).astype(np.int64)
+    c = jnp.asarray(codes).astype(jnp.int32)
     k, n = c.shape
     kp = -(-k // pack) * pack
     if kp != k:
-        c = np.concatenate([c, np.zeros((kp - k, n), np.int64)], axis=0)
-    u = (c & ((1 << bits) - 1)).astype(np.uint8)
-    u = u.reshape(kp // pack, pack, n)
-    out = np.zeros((kp // pack, n), np.uint8)
-    for i in range(pack):
-        out |= u[:, i, :] << (bits * i)
-    return jnp.asarray(out)
+        c = jnp.pad(c, ((0, kp - k), (0, 0)))
+    u = (c & ((1 << bits) - 1)).reshape(kp // pack, pack, n)
+    out = u[:, 0, :]
+    for i in range(1, pack):
+        out = out | (u[:, i, :] << (bits * i))
+    return out.astype(jnp.uint8)
 
 
 def unpack_codes_kmajor(wp: jax.Array, bits: int,
@@ -251,7 +249,7 @@ def pack_linear(w: jax.Array, step: jax.Array, sa, bits: int) -> PackedLinear:
     if bits == 8:
         wp = jnp.asarray(codes, jnp.int8)
     else:
-        wp = pack_codes_kmajor(np.asarray(codes, np.int64), bits)
+        wp = pack_codes_kmajor(codes, bits)
     return PackedLinear(wp=wp, scale=scale,
                         sa=jnp.asarray(sa, jnp.float32), bits=int(bits),
                         k_dim=int(k))

@@ -35,7 +35,7 @@ def _hist_kernel(codes_ref, out_ref, *, n_bins: int):
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "block_rows", "interpret"))
 def histogram(codes: jax.Array, n_bins: int, block_rows: int = 64,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """Counts of int codes in [0, n_bins). codes: int32 (n,) -> (n_bins,) f32."""
     n = codes.shape[0]
     tile = block_rows * LANE

@@ -16,7 +16,7 @@ wasted on masked tiles — ~2× prefill FLOP reduction).
 ``kv_decode_attention`` is the DECODE counterpart over a QUANTIZED KV cache
 (kernels/kv_quant.py layout): one query token per request streams int8 /
 packed-int4 K/V code tiles from HBM and dequantizes them IN-REGISTER inside
-the score and value matmuls — a full-precision cache is never materialized
+the score and value sums — a full-precision cache is never materialized
 in HBM, so the decode roofline reads 1 (or 0.5) bytes per cache element
 instead of 2–4.
 
@@ -81,57 +81,115 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 # ------------------------------------------------- quantized-cache decode
-def _kv_decode_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, pos_ref, o_ref,
+# Both decode kernels take every head of one request per grid step: a K/V
+# code tile is (rows, Hkv, X), whose last two dims are the cache's own, as
+# the TPU block rule asks.  Scores and the value sum are elementwise
+# products reduced over lanes (D) and over rows, so no head ever has to be
+# sliced out of the middle dim.  Packed int4 splits D into its two nibble
+# slots ("parts"): q, the K scale and the output travel part-major,
+# (B, parts, group, Hkv, D // parts), so no lane interleave happens
+# in-kernel; the wrappers reorder them outside.
+
+def _to_parts(x: jax.Array, parts: int) -> jax.Array:
+    """(..., D) -> (parts, ..., D // parts); part i holds channels i::parts
+    (pack4's nibble order)."""
+    y = x.reshape(*x.shape[:-1], x.shape[-1] // parts, parts)
+    return jnp.moveaxis(y, -1, 0)
+
+
+def _q_parts(q: jax.Array, hkv: int, parts: int) -> jax.Array:
+    """q (B, H, D) -> (B, parts, group, Hkv, D // parts); head h is KV head
+    h // group, member h % group (the jnp.repeat GQA layout)."""
+    b, h, d = q.shape
+    y = _to_parts(q.astype(jnp.float32).reshape(b, hkv, h // hkv, d), parts)
+    return y.transpose(1, 0, 3, 2, 4)
+
+
+def _out_from_parts(o: jax.Array) -> jax.Array:
+    """Inverse of _q_parts: (B, parts, group, Hkv, dp) -> (B, H, D)."""
+    b, parts, group, hkv, dp = o.shape
+    return o.transpose(0, 3, 2, 4, 1).reshape(b, hkv * group, dp * parts)
+
+
+def _decode_block(q_ref, kq_ref, ks_ref, vq_ref, vs, live, m_ref, l_ref,
+                  acc_ref, *, bits: int, scale: float):
+    """One online-softmax step of every head over one K/V row block.
+
+    kq_ref/vq_ref: (1, rows, Hkv, dp) codes; vs: (rows, Hkv) per-token V
+    scales; live: (rows, 1, 1) bool — rows past the position contribute
+    exactly 0 (their V is zeroed too, so a poisoned row's NaN cannot smear
+    through 0 * NaN).  K dequantizes as codes * per-channel scale, as in
+    kv_quant.dequant_k; V's per-token scale folds into the probabilities.
+    """
+    k = [c.astype(jnp.float32) * ks_ref[0, i][None]
+         for i, c in enumerate(kv_quant.code_parts(kq_ref[0], bits))]
+    v = [c.astype(jnp.float32)
+         for c in kv_quant.code_parts(vq_ref[0], bits)]
+    vs = vs.astype(jnp.float32)[:, :, None]             # (rows, Hkv, 1)
+    for g in range(m_ref.shape[0]):
+        s = sum(jnp.sum(k[i] * q_ref[0, i, g][None], axis=-1, keepdims=True)
+                for i in range(len(k))) * scale          # (rows, Hkv, 1)
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_ref[g]                                # (Hkv, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
+        pv = jnp.where(live, p * vs, 0.0)
+        for i in range(len(v)):
+            acc_ref[i, g] = acc_ref[i, g] * alpha + jnp.sum(pv * v[i],
+                                                            axis=0)
+        m_ref[g] = m_new
+
+
+def _decode_finish(o_ref, l_ref, acc_ref):
+    for i in range(acc_ref.shape[0]):
+        o_ref[0, i] = (acc_ref[i] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+def _decode_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _decode_scratch(parts: int, group: int, hkv: int, dp: int) -> list:
+    return [pltpu.VMEM((group, hkv, 1), jnp.float32),
+            pltpu.VMEM((group, hkv, 1), jnp.float32),
+            pltpu.VMEM((parts, group, hkv, dp), jnp.float32)]
+
+
+def _kv_decode_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
                       m_ref, l_ref, acc_ref, *, bs: int, ns: int, bits: int,
                       scale: float):
-    j = pl.program_id(2)          # kv block (innermost)
+    j = pl.program_id(1)          # kv block (innermost)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _decode_init(m_ref, l_ref, acc_ref)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
     # Blocks entirely past this request's position are fully masked — skip
     # them (an evicted slot's out-of-range position keeps every block live;
     # its output is discarded upstream, matching the full-dtype path).
     @pl.when(j * bs <= pos)
     def _step():
-        q = q_ref[0].astype(jnp.float32)                 # (1, D)
-        kq = kq_ref[0, :, 0, :]                          # (bs, D or D//2)
-        # kv_quant.unpack4 is the ONE definition of the nibble layout —
-        # pure jnp, so it traces inside the kernel body unchanged.
-        k = kq.astype(jnp.float32) if bits == 8 else kv_quant.unpack4(kq)
-        k = k * ks_ref[0].astype(jnp.float32)            # per-channel (1, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m_prev = m_ref[...]                              # (1, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                           # (1, bs)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        vq = vq_ref[0, :, 0, :]
-        v = vq.astype(jnp.float32) if bits == 8 else kv_quant.unpack4(vq)
-        v = v * vs_ref[0].astype(jnp.float32)            # per-token (bs, 1)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+        _decode_block(q_ref, kq_ref, ks_ref, vq_ref, vs_ref[0], kpos <= pos,
+                      m_ref, l_ref, acc_ref, bits=bits, scale=scale)
 
     @pl.when(j == ns - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _decode_finish(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bs", "interpret"))
 def kv_decode_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
                         vq: jax.Array, v_scale: jax.Array,
                         positions: jax.Array, bits: int = 8, bs: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """Fused dequant decode attention over a quantized KV cache.
 
     q: (B, H, D) — one query token per request.
@@ -140,61 +198,58 @@ def kv_decode_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
     per-token; positions: (B,) int32 — rows with s_pos <= positions[b] are
     attended (the serving validity mask).  Returns (B, H, D) f32.
 
-    Grid (B, H, ns), S innermost; K/V code tiles dequantize in-register
-    (codes * scale) right before their matmuls, so HBM only ever streams
-    the 1-byte (or half-byte) codes.  D is deliberately NOT blocked
-    (head_dim is small), so only S must divide ``bs`` — the dispatch layer
+    Grid (B, ns), S innermost, all heads per step; K/V code tiles
+    dequantize in-register right before use, so HBM only ever streams the
+    1-byte (or half-byte) codes.  D is deliberately NOT blocked (head_dim
+    is small), so only S must divide ``bs`` — the dispatch layer
     (kernels/ops) picks a divisor for non-tile-multiple S.
     """
     b, h, d = q.shape
     _, s, hkv, dp = kq.shape
     assert h % hkv == 0, (h, hkv)
     group = h // hkv
-    assert dp == (d if bits == 8 else d // 2), (kq.shape, d, bits)
+    parts = 1 if bits == 8 else 2
+    assert dp * parts == d, (kq.shape, d, bits)
     assert vq.shape == kq.shape, (vq.shape, kq.shape)
     bs = min(bs, s)
     assert s % bs == 0, (s, bs)
     ns = s // bs
-    grid = (b, h, ns)
-    pos2 = positions.reshape(b, 1).astype(jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,           # positions
+        grid=(b, ns),
+        in_specs=[
+            pl.BlockSpec((1, parts, group, hkv, dp),
+                         lambda b, j, p: (b, 0, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, dp), lambda b, j, p: (b, j, 0, 0)),
+            pl.BlockSpec((1, parts, hkv, dp), lambda b, j, p: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, dp), lambda b, j, p: (b, j, 0, 0)),
+            pl.BlockSpec((1, bs, hkv), lambda b, j, p: (b, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, parts, group, hkv, dp),
+                               lambda b, j, p: (b, 0, 0, 0, 0)),
+        scratch_shapes=_decode_scratch(parts, group, hkv, dp),
+    )
     out = pl.pallas_call(
         functools.partial(_kv_decode_kernel, bs=bs, ns=ns, bits=bits,
                           scale=d ** -0.5),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda b, h, j, g=group: (b, j, h // g, 0)),
-            pl.BlockSpec((1, 1, d), lambda b, h, j, g=group: (b, h // g, 0)),
-            pl.BlockSpec((1, bs, 1, dp),
-                         lambda b, h, j, g=group: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, h, j, g=group: (b, j, h // g)),
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, h, j: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, parts, group, hkv, dp),
+                                       jnp.float32),
         interpret=interpret,
-    )(q, kq, k_scale, vq, v_scale, pos2)
-    return out
+    )(positions.astype(jnp.int32), _q_parts(q, hkv, parts), kq,
+      jnp.moveaxis(_to_parts(k_scale, parts), 0, 1), vq, v_scale)
+    return _out_from_parts(out)
 
 
 def _paged_kv_decode_kernel(tbl_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref,
                             vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
                             page: int, np_max: int, bits: int, scale: float):
-    j = pl.program_id(2)          # logical page (innermost)
+    j = pl.program_id(1)          # logical page (innermost)
     b = pl.program_id(0)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _decode_init(m_ref, l_ref, acc_ref)
 
     pos = pos_ref[b, 0]
 
@@ -203,34 +258,14 @@ def _paged_kv_decode_kernel(tbl_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref,
     # keeps unmapped physical pages, even NaN-poisoned ones, unread).
     @pl.when(j * page <= pos)
     def _step():
-        q = q_ref[0].astype(jnp.float32)                 # (1, D)
-        kq = kq_ref[0, :, 0, :]                          # (page, D or D//2)
-        k = kq.astype(jnp.float32) if bits == 8 else kv_quant.unpack4(kq)
-        k = k * ks_ref[0].astype(jnp.float32)            # per-channel (1, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        live = kpos <= pos
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]                              # (1, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                           # (1, page)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        vq = vq_ref[0, :, 0, :]
-        v = vq.astype(jnp.float32) if bits == 8 else kv_quant.unpack4(vq)
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]  # per-token
-        # zero masked V rows: their weight is exactly 0, but a poisoned
-        # page's NaN would still smear through 0 * NaN in the dot.
-        v = jnp.where(live[0][:, None], v, 0.0)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1, 1),
+                                                   0)
+        _decode_block(q_ref, kq_ref, ks_ref, vq_ref, vs_ref[0], kpos <= pos,
+                      m_ref, l_ref, acc_ref, bits=bits, scale=scale)
 
     @pl.when(j == np_max - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        _decode_finish(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -238,7 +273,7 @@ def paged_kv_decode_attention(q: jax.Array, kq_pool: jax.Array,
                               k_scale: jax.Array, vq_pool: jax.Array,
                               v_scale_pool: jax.Array, tbl: jax.Array,
                               positions: jax.Array, bits: int = 8,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool = False) -> jax.Array:
     """Fused dequant decode attention over a PAGED quantized KV cache.
 
     q: (B, H, D) — one query token per slot.
@@ -248,69 +283,68 @@ def paged_kv_decode_attention(q: jax.Array, kq_pool: jax.Array,
     per-slot per-channel; tbl: (B, n_pages) int32 block table;
     positions: (B,) int32.  Returns (B, H, D) f32.
 
-    Grid (B, H, n_pages), pages innermost: the block table rides in as a
-    SCALAR-PREFETCH operand, so each K/V tile's index map dereferences
-    ``tbl[b, j]`` — the kernel streams physical pages straight from HBM
-    in logical order, dequantizes in-register, and never materializes
-    the gathered sequence (the ref oracle's gather is the semantic spec,
-    not the traffic model).  One page (16 rows by default) per grid step
-    is sublane-aligned but narrow; fusing multiple pages per step is a
-    perf follow-up, not a correctness concern.
+    Grid (B, n_pages), pages innermost, all heads per step: the block
+    table rides in as a SCALAR-PREFETCH operand, so each K/V tile's index
+    map dereferences ``tbl[b, j]`` — the kernel streams physical pages
+    straight from HBM in logical order, dequantizes in-register, and never
+    materializes the gathered sequence (the ref oracle's gather is the
+    semantic spec, not the traffic model).  One page (16 rows by default)
+    per grid step is sublane-aligned but narrow; fusing multiple pages per
+    step is a perf follow-up, not a correctness concern.
 
-    Tensor-parallel note: every count here — grid H, the GQA ``group``,
-    ``hkv`` — derives from the LOCAL operand shapes, so under
-    ``shard_map`` with head-sharded pools each shard streams pages for
-    ITS KV heads through the same replicated block table with zero mesh
-    awareness (DESIGN.md §3, paged sharding).
+    Tensor-parallel note: every count here — the GQA ``group``, ``hkv`` —
+    derives from the LOCAL operand shapes, so under ``shard_map`` with
+    head-sharded pools each shard streams pages for ITS KV heads through
+    the same replicated block table with zero mesh awareness (DESIGN.md
+    §3, paged sharding).
     """
     b, h, d = q.shape
     p_phys, page, hkv, dp = kq_pool.shape
     assert h % hkv == 0, (h, hkv)
     group = h // hkv
-    assert dp == (d if bits == 8 else d // 2), (kq_pool.shape, d, bits)
+    parts = 1 if bits == 8 else 2
+    assert dp * parts == d, (kq_pool.shape, d, bits)
     assert vq_pool.shape == kq_pool.shape, (vq_pool.shape, kq_pool.shape)
     assert v_scale_pool.shape == kq_pool.shape[:3], v_scale_pool.shape
     np_max = tbl.shape[1]
-    grid = (b, h, np_max)
     pos2 = positions.reshape(b, 1).astype(jnp.int32)
 
     # index maps receive the grid indices first, then the scalar-prefetch
-    # refs (tbl, positions) as trailing arguments
-    def kv_map(b, h, j, t, p, g=group):
-        # physical page from the prefetched table; clamp so stale entries
-        # (masked pages) can never index out of the pool
-        return (jnp.clip(t[b, j], 0, p_phys - 1), 0, h // g, 0)
+    # refs (tbl, positions) as trailing arguments; the physical page is
+    # clamped so stale entries (masked pages) never index out of the pool
+    def kv_map(b, j, t, p):
+        return (jnp.clip(t[b, j], 0, p_phys - 1), 0, 0, 0)
 
-    def vs_map(b, h, j, t, p, g=group):
-        return (jnp.clip(t[b, j], 0, p_phys - 1), 0, h // g)
+    def vs_map(b, j, t, p):
+        return (jnp.clip(t[b, j], 0, p_phys - 1), 0, 0)
+
+    def slot_map(b, j, t, p):
+        return (b, 0, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # tbl, positions
-        grid=grid,
+        grid=(b, np_max),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, h, j, t, p: (b, h, 0)),
-            pl.BlockSpec((1, page, 1, dp), kv_map),
-            pl.BlockSpec((1, 1, d),
-                         lambda b, h, j, t, p, g=group: (b, h // g, 0)),
-            pl.BlockSpec((1, page, 1, dp), kv_map),
-            pl.BlockSpec((1, page, 1), vs_map),
+            pl.BlockSpec((1, parts, group, hkv, dp), slot_map),
+            pl.BlockSpec((1, page, hkv, dp), kv_map),
+            pl.BlockSpec((1, parts, hkv, dp),
+                         lambda b, j, t, p: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, hkv, dp), kv_map),
+            pl.BlockSpec((1, page, hkv), vs_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, h, j, t, p: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, parts, group, hkv, dp), slot_map),
+        scratch_shapes=_decode_scratch(parts, group, hkv, dp),
     )
     out = pl.pallas_call(
         functools.partial(_paged_kv_decode_kernel, page=page, np_max=np_max,
                           bits=bits, scale=d ** -0.5),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, parts, group, hkv, dp),
+                                       jnp.float32),
         interpret=interpret,
-    )(tbl.astype(jnp.int32), pos2, q, kq_pool, k_scale, vq_pool,
-      v_scale_pool)
-    return out
+    )(tbl.astype(jnp.int32), pos2, _q_parts(q, hkv, parts), kq_pool,
+      jnp.moveaxis(_to_parts(k_scale, parts), 0, 1), vq_pool, v_scale_pool)
+    return _out_from_parts(out)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
@@ -318,7 +352,7 @@ def paged_kv_decode_attention(q: jax.Array, kq_pool: jax.Array,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, bq: int = 128, bk: int = 128,
                     scale: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B, H, S, D); k, v: (B, Hkv, S, D) with H % Hkv == 0 -> (B, H, S, D)."""
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape

@@ -72,13 +72,22 @@ def pack4(codes: jnp.ndarray) -> jnp.ndarray:
     return (c[..., 0] | (c[..., 1] << 4)).astype(jnp.uint8)
 
 
+def code_parts(codes: jnp.ndarray, bits: int) -> list:
+    """Code container (..., X) -> sign-extended int32 codes, one array per
+    packed slot: ``[codes]`` for int8, ``[low nibbles, high nibbles]`` for
+    packed int4 (channels 0::2 and 1::2).  Everything is widened to int32
+    before any shift, so it also lowers inside a TPU kernel, whose vector
+    unit has no 8-bit shift or compare."""
+    w = codes.astype(jnp.int32)
+    if bits == 8:
+        return [w]
+    # shift the nibble to the top of the word, arithmetic-shift it back
+    return [(w << 28) >> 28, (w << 24) >> 28]
+
+
 def unpack4(packed: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
     """Inverse of pack4: uint8 (..., D//2) -> sign-extended codes (..., D)."""
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    w = jnp.stack([lo, hi], axis=-1)
+    w = jnp.stack(code_parts(packed, 4), axis=-1)
     return w.reshape(*packed.shape[:-1], packed.shape[-1] * 2).astype(dtype)
 
 

@@ -29,7 +29,7 @@ def _lsq_kernel(x_ref, step_ref, bits_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def lsq_fakequant(x: jax.Array, step: jax.Array, bits: jax.Array,
-                  block_rows: int = 256, interpret: bool = True) -> jax.Array:
+                  block_rows: int = 256, interpret: bool = False) -> jax.Array:
     """Fake-quantize a tensor of any shape; returns same shape/dtype."""
     orig_shape = x.shape
     flat = x.reshape(-1)

@@ -27,25 +27,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _unpack_w4_block(wp):
-    """(bk//2, bn) uint8 -> (bk, bn) bf16 sign-extended codes."""
-    lo = (wp & 0xF).astype(jnp.int8)
-    hi = ((wp >> 4) & 0xF).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    w = jnp.stack([lo, hi], axis=1)                      # (bk//2, 2, bn)
-    return w.reshape(wp.shape[0] * 2, wp.shape[1]).astype(jnp.bfloat16)
+def _unpack_part(wp, bits: int, i: int):
+    """Codes of packed slot ``i`` of a (rows, bn) uint8 tile as bf16.
 
-
-def _unpack_w2_block(wp):
-    """(bk//4, bn) uint8 -> (bk, bn) bf16 codes in [-2, 1]."""
-    parts = []
-    for i in range(4):
-        c = ((wp >> (2 * i)) & 0x3).astype(jnp.int8)
-        c = jnp.where(c >= 2, c - 4, c)
-        parts.append(c)
-    w = jnp.stack(parts, axis=1)                         # (bk//4, 4, bn)
-    return w.reshape(wp.shape[0] * 4, wp.shape[1]).astype(jnp.bfloat16)
+    Slot ``i`` holds K-row ``pack*r + i`` of packed row ``r`` in bits
+    ``[bits*i, bits*(i+1))``.  The byte widens to int32 first: v5e's vector
+    unit has no 8-bit shift or compare.  Shifting the slot to the top of the
+    word and arithmetic-shifting it back down sign-extends it."""
+    w = wp.astype(jnp.int32)
+    code = (w << (32 - bits * (i + 1))) >> (32 - bits)
+    return code.astype(jnp.float32).astype(jnp.bfloat16)
 
 
 def _qmm_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, nk: int,
@@ -56,10 +47,18 @@ def _qmm_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    unpack = _unpack_w4_block if bits == 4 else _unpack_w2_block
-    w = unpack(wp_ref[...])
-    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.bfloat16), w,
-                            preferred_element_type=jnp.float32)
+    # The x tile arrives slot-major (see quant_matmul): its columns
+    # [i*rows, (i+1)*rows) are the K-rows that packed slot i holds, so each
+    # slot is one lane-aligned matmul and no row interleave is needed.
+    wp = wp_ref[...]
+    rows = wp.shape[0]
+    x = x_ref[...].astype(jnp.bfloat16)
+    acc = acc_ref[...]
+    for i in range(8 // bits):
+        acc += jnp.dot(x[:, i * rows:(i + 1) * rows],
+                       _unpack_part(wp, bits, i),
+                       preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -71,7 +70,7 @@ def _qmm_kernel(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, nk: int,
                                              "interpret", "out_dtype"))
 def quant_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array,
                  bits: int = 4, bm: int = 128, bn: int = 128, bk: int = 512,
-                 interpret: bool = True, out_dtype=jnp.float32) -> jax.Array:
+                 interpret: bool = False, out_dtype=jnp.float32) -> jax.Array:
     """x (M, K) @ packed-weights (K//pack, N) -> (M, N).
 
     bits in {4, 2}; pack = 8 // bits. scale: (N,) per-output-channel fp32
@@ -86,6 +85,9 @@ def quant_matmul(x: jax.Array, w_packed: jax.Array, scale: jax.Array,
     bk = min(bk, kdim)
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0 and bk % pack == 0
     grid = (m // bm, n // bn, kdim // bk)
+    # Reorder each bk-block of x's columns slot-major: column pack*r + i
+    # moves to i*(bk//pack) + r, matching the kernel's per-slot matmuls.
+    x = x.reshape(m, grid[2], bk // pack, pack).swapaxes(2, 3).reshape(m, kdim)
     out = pl.pallas_call(
         functools.partial(_qmm_kernel, nk=grid[2], bits=bits),
         grid=grid,

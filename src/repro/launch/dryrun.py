@@ -212,7 +212,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     # donate the big mutable buffers: train state (arg 0) / decode caches
     donate = (0,) if shape.kind == "train" else \
         ((2,) if shape.kind == "decode" else ())
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0

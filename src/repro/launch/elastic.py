@@ -19,8 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
-
+from repro.launch.mesh import make_mesh
 from repro.parallel.context import ParallelContext
 
 
@@ -50,7 +49,7 @@ def plan_mesh(n_devices: int, model_degree: int, global_batch: int,
 
 
 def build(plan: ElasticPlan):
-    mesh = jax.make_mesh(plan.mesh_shape, plan.axis_names)
+    mesh = make_mesh(plan.mesh_shape, plan.axis_names)
     ctx = ParallelContext(mesh=mesh, batch_axes=("data",))
     return mesh, ctx
 

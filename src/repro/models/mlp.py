@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import quant
 from repro.kernels import ops as kops
 from repro.models.common import init_qdense, qproj
-from repro.parallel.compat import shard_map
 
 
 def act_fn(kind: str, x):
@@ -269,7 +268,7 @@ def moe_apply(p, x, bits, cfg, ctx):
                         for k in bank}
             return P(ma, None, None)
 
-        y_flat = shard_map(
+        y_flat = jax.shard_map(
             shard_fn, mesh=ctx.mesh,
             in_specs=(P(bspec, None), P(bspec, None), P(bspec, None),
                       wspec(qgate), wspec(qup), wspec(qdown),

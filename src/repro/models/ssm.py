@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from repro.core import quant
 from repro.models.common import init_qdense, qproj
-from repro.parallel.compat import shard_map
 
 MAMBA_CHUNK = 128
 MLSTM_CHUNK = 128
@@ -342,7 +341,7 @@ def slstm_apply(p, x, bits, cfg, mode: str, state, ctx=None):
     if batch_shardable:
         from jax.sharding import PartitionSpec as P
         bspec = ctx.batch_spec
-        h_all, carry = shard_map(
+        h_all, carry = jax.shard_map(
             run_scan, mesh=ctx.mesh,
             in_specs=(P(bspec, None, None), P()),
             out_specs=(P(bspec, None, None),

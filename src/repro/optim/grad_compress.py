@@ -21,7 +21,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import shard_map
 
 
 def _quant_chunks(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -71,7 +70,7 @@ def compressed_allreduce(grads, mesh, batch_axes: Tuple[str, ...],
         red = jax.tree.map(lambda r, gl: r.astype(gl.dtype), red, g)
         return red, new_e
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), grads),
                   jax.tree.map(lambda _: P(), errors)),

@@ -18,7 +18,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.parallel.compat import shard_map
 
 
 def pipeline_apply(block_fn: Callable, stage_params, x_microbatches,
@@ -69,7 +68,7 @@ def pipeline_apply(block_fn: Callable, stage_params, x_microbatches,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False,

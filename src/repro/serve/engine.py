@@ -62,7 +62,7 @@ packed weights shard along output channels (attention heads for QKV, d_ff
 for gate/up) and input channels (heads for O, d_ff for down — repacked so
 no nibble byte straddles a shard), the KV cache (codes AND scales) shards
 along the KV-head axis, and prefill/decode run under
-``parallel/compat.shard_map`` with exactly two psums per block (after the
+``jax.shard_map`` with exactly two psums per block (after the
 O-projection and after the MLP down-projection).  Both cache layouts
 compose: a PAGED cache shards its physical page pools (``pk/pv``,
 ``pkq/pvq`` + per-page ``pv_scale``) on the same KV-head axis while the
@@ -106,7 +106,7 @@ from repro.core import quant
 from repro.kernels import ops as kops
 from repro.models import transformer as tf
 from repro.models.layout import LayerBuckets
-from repro.parallel import compat, sharding
+from repro.parallel import sharding
 from repro.parallel.context import local_context
 from repro.serve import kv_cache, packing, paging, residency, sampling
 from repro.serve.config import (RECURRENT_MIXERS, DraftSpec, EngineSpec,
@@ -382,7 +382,7 @@ class ServeEngine:
         # keep the unjitted shard_map'd callables around: they are the
         # exact programs jit compiles, and repro.analysis traces THEM
         # (dispatch_closures) to check the collective-count contract
-        self._prefill_sm = compat.shard_map(
+        self._prefill_sm = jax.shard_map(
             self._prefill_impl, mesh=self.mesh,
             in_specs=(self._pspecs, self._pa_specs, P(None, None), P(None)),
             out_specs=(P(None, None), self._pre_specs),
@@ -407,7 +407,7 @@ class ServeEngine:
                     params, pa, layers, lengths, tok, active, key, nonces,
                     t0, n_steps, self._cfg_local, self._tp_axis,
                     local_context())
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(self._pspecs, self._pa_specs, self._cache_specs,
                           P(None), P(None, None), P(None),

@@ -244,8 +244,8 @@ def _shard_row_packed(p: PackedLinear, n_shards: int) -> PackedLinear:
             for i in range(n_shards)]
         return jnp.concatenate(slabs, axis=0)
 
-    codes = np.asarray(quant.unpack_codes_kmajor(p.wp, p.bits,
-                                                 jnp.int8))[..., :p.k_dim, :]
+    codes = quant.unpack_codes_kmajor(p.wp, p.bits,
+                                      jnp.int8)[..., :p.k_dim, :]
     if codes.ndim == 2:
         wp = repack(codes)
     else:                               # bucketed (m, Kp, N) layer stack
@@ -284,7 +284,7 @@ def shard_packed_params(pparams: dict, cfg, n_shards: int,
     Row-parallel leaves are repacked per shard (`_shard_row_packed`) and
     carry the LOCAL k_dim; everything else keeps its buffers and gets the
     column/replicated spec.  The spec tree has the same treedef as the
-    params tree (P leaves), ready for ``compat.shard_map`` in_specs and
+    params tree (P leaves), ready for ``jax.shard_map`` in_specs and
     ``jax.device_put`` placement.
     """
     reason = tp_shardable(cfg, n_shards)

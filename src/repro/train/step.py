@@ -21,7 +21,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import transformer as tf
 from repro.optim import grad_compress
-from repro.parallel.compat import shard_map
 
 
 class TrainState(NamedTuple):
@@ -148,7 +147,7 @@ def make_train_step(cfg, ctx, optimizer, *, loss_fn: Optional[Callable] = None,
         if errors is None:
             errors = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                   state.params)
-        new_params, new_opt, new_err, metrics = shard_map(
+        new_params, new_opt, new_err, metrics = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(), P(), P(), P(), batch_pspecs(batch, axis)),
             out_specs=(P(), P(), P(), P()),

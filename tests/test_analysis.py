@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 from repro.analysis import (contracts, deadcode, harness, jaxpr_checks,
                             lint_rules, report)
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 from repro.serve.engine import DispatchClosure
 
 
@@ -54,8 +55,7 @@ def test_iter_eqns_recurses_into_scan():
 
 
 def test_count_primitive_counts_static_structure():
-    mesh = jax.make_mesh((1,), ("model",))
-    from repro.parallel import compat
+    mesh = make_mesh((1,), ("model",))
 
     def fn(x):
         def body(c, _):
@@ -65,8 +65,8 @@ def test_count_primitive_counts_static_structure():
 
     # check_vma=False matches the engine's shard_map mode — with vma
     # checking on, psum lowers as a different primitive ("psum2")
-    sm = compat.shard_map(fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          check_vma=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     closed = jax.make_jaxpr(sm)(jnp.float32(1.0))
     # one psum in the scan BODY counts once — program structure, not
     # executed collectives (5 iterations still == 1 static psum)
@@ -192,16 +192,15 @@ class _ThreePsumEngine:
         return 1
 
     def dispatch_closures(self):
-        mesh = jax.make_mesh((1,), ("model",))
-        from repro.parallel import compat
+        mesh = make_mesh((1,), ("model",))
 
         def decode(x):
             h = jax.lax.psum(x * 2.0, "model")         # attn out-proj
             h = jax.lax.psum(h + 1.0, "model")         # ffn down-proj
             return jax.lax.psum(h * 0.5, "model")      # the regression
 
-        sm = compat.shard_map(decode, mesh=mesh, in_specs=(P(),),
-                              out_specs=P(), check_vma=False)
+        sm = jax.shard_map(decode, mesh=mesh, in_specs=(P(),),
+                           out_specs=P(), check_vma=False)
         return {"decode": DispatchClosure("decode", sm,
                                           (jnp.float32(1.0),))}
 

@@ -7,6 +7,7 @@ import pytest
 
 from repro import configs
 from repro.core import knapsack
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as tf
 from repro.models.layout import LayerBuckets
 from repro.parallel.context import local_context
@@ -670,7 +671,7 @@ def test_sharded_engine_single_shard_matches_unsharded(setup):
     bit-exactness ladder lives in tests/test_sharding.py)."""
     cfg, ctx, params, policy, pa, qparams = setup
     pparams = pack_params(params, policy.as_arrays(), cfg)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     e1 = ServeEngine(cfg=cfg, params=pparams, policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed", cache="quantized", cache_bits=8))
     eS = ServeEngine(cfg=cfg, params=pack_params(params, policy.as_arrays(), cfg), policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed", cache="quantized", cache_bits=8, mesh=mesh))
     rng = np.random.default_rng(26)
@@ -685,11 +686,11 @@ def test_sharded_engine_validation(setup):
     """Sharded serving fails loudly on layouts it cannot shard: fake-quant
     weights, head counts the mesh does not divide, recurrent mixers."""
     cfg, ctx, params, policy, pa, qparams = setup
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="packed"):
         ServeEngine(cfg=cfg, params=qparams, policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(mesh=mesh))
     pparams = pack_params(params, policy.as_arrays(), cfg)
-    bad = jax.make_mesh((1,), ("data",))
+    bad = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="model"):
         ServeEngine(cfg=cfg, params=pparams, policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed", mesh=bad))
     from repro.serve import packing as packing_mod
@@ -898,7 +899,7 @@ def test_paged_engine_validation(setup):
     with pytest.raises(ValueError, match="GQA"):
         ServeEngine(cfg=xcfg, params=xq, policy_arrays=xpa, ctx=ctx, max_seq=64, spec=EngineSpec(cache_layout="paged"))
     pparams = pack_params(params, policy.as_arrays(), cfg)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # mesh= + cache_layout="paged" validates AND serves: the sharded
     # paged engine round-trips a short greedy generate on a 1-device
     # model mesh (the shard_map path; multi-device parity lives in

@@ -12,9 +12,10 @@ def _run(code: str, timeout=560):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     # These tests shard over FAKE host devices (XLA_FLAGS in HEADER) — pin
-    # the platform so hosts with a half-configured accelerator plugin don't
-    # burn a 60s+ TPU probe per subprocess (or grab 1 real device and make
-    # the 8-device mesh impossible).
+    # the platform: on a host with libtpu installed but no chip attached,
+    # JAX would spend a minute probing for a TPU per subprocess, and on a
+    # TPU host it would take one real device and make the 8-device mesh
+    # impossible.
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
@@ -26,6 +27,7 @@ HEADER = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -53,7 +55,7 @@ nl, ml = step_l(state_l, batch)
 mesh = make_test_mesh(2, 4)
 ctx = make_context(mesh)
 state_s = init_train_state(cfg, opt, jax.random.PRNGKey(0), policy)
-with mesh:
+with jax.set_mesh(mesh):
     step_s = jax.jit(make_train_step(cfg, ctx, opt))
     ns, ms = step_s(state_s, batch)
 np.testing.assert_allclose(float(ml["loss"]), float(ms["loss"]), rtol=1e-4)
@@ -86,7 +88,7 @@ y_local, aux_l = mlp.moe_apply(p, x, bits, cfg, local_context())
 
 mesh = make_test_mesh(2, 4)
 ctx = make_context(mesh)
-with mesh:
+with jax.set_mesh(mesh):
     y_shard, aux_s = jax.jit(
         lambda p, x: mlp.moe_apply(p, x, bits, cfg, ctx))(p, x)
 np.testing.assert_allclose(np.asarray(y_local), np.asarray(y_shard),
@@ -108,13 +110,13 @@ from repro.launch.mesh import make_test_mesh, make_context
 cfg = configs.get_config("olmo-1b").smoke()
 opt = AdamW(learning_rate=1e-3)
 policy = tf.build_policy(cfg)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 from repro.parallel.context import ParallelContext
 ctx = ParallelContext(mesh=mesh, batch_axes=("data",))
 batch = make_batch(0, 0, 8, 128, cfg.vocab)
 
 s0 = init_train_state(cfg, opt, jax.random.PRNGKey(0), policy)
-with mesh:
+with jax.set_mesh(mesh):
     exact = jax.jit(make_train_step(cfg, ctx, opt))
     comp = jax.jit(make_train_step(cfg, ctx, opt, grad_compression="int8"))
     ne, _ = exact(s0, batch)
@@ -152,7 +154,7 @@ arrays = policy.as_arrays()
 pa = jax.tree.map(jnp.asarray, arrays)
 rng = np.random.default_rng(2)
 prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))     # all 8 host devices
+mesh = make_mesh((2, 4), ("data", "model"))     # all 8 host devices
 for cache, bits in (("full", 8), ("quantized", 8), ("quantized", 4)):
     e1 = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed", cache=cache, cache_bits=bits))
     eS = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed", cache=cache, cache_bits=bits, mesh=mesh))
@@ -188,7 +190,7 @@ arrays = policy.as_arrays()
 pa = jax.tree.map(jnp.asarray, arrays)
 rng = np.random.default_rng(7)
 prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))     # all 8 host devices
+mesh = make_mesh((2, 4), ("data", "model"))     # all 8 host devices
 
 def mk(cache, bits, layout, m, **kw):
     if layout == "paged":
@@ -237,7 +239,7 @@ params = tf.init_params(cfg, jax.random.PRNGKey(0))
 policy = tf.build_policy(cfg)
 arrays = policy.as_arrays()
 pa = jax.tree.map(jnp.asarray, arrays)
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 
 def mk(m, **kw):
     return ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg),
@@ -283,7 +285,7 @@ mixed = policy.apply_selection(knapsack.select_for_budget(
     policy, knapsack.synthetic_gains(policy), budget_frac=0.7).take)
 arrays = mixed.as_arrays()
 pa = jax.tree.map(jnp.asarray, arrays)
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 rng = np.random.default_rng(3)
 prompt = jnp.asarray(rng.integers(0, cfg.vocab, (1, 12)), jnp.int32)
 e1 = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=64, spec=EngineSpec(weights="packed"))
@@ -330,7 +332,7 @@ pa = jax.tree.map(jnp.asarray, arrays)
 rng = np.random.default_rng(19)
 prompt = jnp.asarray(rng.integers(0, cfg.vocab, (1, 10)), jnp.int32)
 e1 = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=40, spec=EngineSpec(weights="packed"))
-eS = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=40, spec=EngineSpec(weights="packed", mesh=jax.make_mesh((2,), ("model",))))
+eS = ServeEngine(cfg=cfg, params=pack_params(params, arrays, cfg), policy_arrays=pa, ctx=ctx, max_seq=40, spec=EngineSpec(weights="packed", mesh=make_mesh((2,), ("model",))))
 np.testing.assert_array_equal(np.asarray(eS.generate(prompt, n_new=8)),
                               np.asarray(e1.generate(prompt, n_new=8)))
 print("OK")
@@ -341,7 +343,7 @@ print("OK")
 def test_pipeline_parallel_matches_sequential():
     _run(HEADER + """
 from repro.parallel.pp import pipeline_apply
-mesh = jax.make_mesh((4, 2), ("pod", "model"))
+mesh = make_mesh((4, 2), ("pod", "model"))
 rng = np.random.default_rng(0)
 ws = jnp.asarray(rng.normal(size=(4, 16, 16)) * 0.3, jnp.float32)
 xs = jnp.asarray(rng.normal(size=(8, 2, 16)), jnp.float32)
@@ -376,7 +378,7 @@ policy = tf.build_policy(cfg)
 plan8 = elastic.plan_mesh(8, model_degree=4, global_batch=8)
 mesh8, ctx8 = elastic.build(plan8)
 state = init_train_state(cfg, opt, jax.random.PRNGKey(0), policy)
-with mesh8:
+with jax.set_mesh(mesh8):
     step8 = jax.jit(make_train_step(cfg, ctx8, opt))
     state, _ = step8(state, make_batch(0, 0, 8, 64, cfg.vocab))
 mgr = CheckpointManager({str(tmp_path)!r}, async_save=False)
@@ -387,7 +389,7 @@ plan4 = elastic.plan_mesh(4, model_degree=4, global_batch=8)
 assert plan4.mesh_shape == (1, 4)
 mesh4, ctx4 = elastic.build(plan4)
 _, restored = mgr.restore_latest(state)
-with mesh4:
+with jax.set_mesh(mesh4):
     step4 = jax.jit(make_train_step(cfg, ctx4, opt))
     out, m = step4(restored, make_batch(0, 1, 8, 64, cfg.vocab))
 assert np.isfinite(float(m["loss"]))
