@@ -1,0 +1,9 @@
+"""Put the repository root on the path: the benchmark's modules import as
+``benchmarks.chip.*``."""
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
