@@ -18,6 +18,8 @@
   sampling.py   greedy / temperature / top-k; keys fold (admission nonce,
                 per-request token index) — scheduler-invariant
   scheduler.py  continuous batching: slot admission, per-request stop/evict
+  tracing.py    ``serve.*`` host spans on the profiler's clock (a
+                ``jax.profiler`` session is the only switch)
   config.py     EngineSpec / DraftSpec: the typed, validated serving spec
                 (``ServeEngine(..., spec=EngineSpec(...))`` is the
                 primary constructor; flat kwargs are deprecated)
@@ -31,7 +33,7 @@ The public serving surface is what this module exports: ``ServeEngine``,
 and ``pack_params`` — examples and benches import from here, not from
 submodule paths.
 """
-from repro.serve import paging, residency
+from repro.serve import paging, residency, tracing
 from repro.serve.config import DraftSpec, EngineSpec
 from repro.serve.engine import ServeEngine, quantize_for_serving
 from repro.serve.spec import SpecDecoder
@@ -49,7 +51,7 @@ __all__ = [
     "ServeEngine", "EngineSpec", "DraftSpec", "SpecDecoder",
     "quantize_for_serving",
     "pack_params", "params_are_packed", "resident_weight_bytes",
-    "bf16_resident_weight_bytes", "residency",
+    "bf16_resident_weight_bytes", "residency", "tracing",
     "ServeCache", "QuantizedServeCache", "init_cache", "splice_prefill",
     "paging", "PagedServeCache", "PageAllocator", "PrefixRegistry",
     "SamplerConfig", "GREEDY", "sample",

@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import kv_quant as kvq
-from repro.serve import kv_cache, paging, sampling
+from repro.serve import kv_cache, paging, sampling, tracing
 from repro.serve import spec as spec_mod
 from repro.serve.engine import ServeEngine
 
@@ -225,28 +225,43 @@ class ContinuousBatchingScheduler:
             if self.slots[j] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
-            if self._chunked:
-                if not self._claim_chunked(j, req):
-                    # pool exhausted: defer admission (FIFO preserved)
-                    # until an eviction returns pages to the free list
-                    self.queue.appendleft(req)
-                    return
-                continue
-            if self._paged:
-                last = self._admit_paged(j, req)
-                if last is None:
-                    self.queue.appendleft(req)
-                    return
-            else:
-                last = self._admit_contiguous(j, req)
-            nonce = self._next_nonce()
-            first = int(sampling.sample(
-                last, sampling.slot_keys(self.key,
-                                         jnp.asarray([nonce], jnp.int32),
-                                         jnp.zeros((1,), jnp.int32)),
-                self.engine.sampler)[0])
-            self._begin_decode(j, _Slot(req=req, emitted=[], nonce=nonce),
-                               first)
+            with tracing.span("serve.admit", uid=req.uid) as sp:
+                if self._chunked:
+                    sp.set_metadata(tokens=0, padded=0)
+                    admitted = self._claim_chunked(j, req)
+                else:
+                    admitted = self._admit_whole(j, req, sp)
+            if not admitted:
+                # pool exhausted: defer admission (FIFO preserved)
+                # until an eviction returns pages to the free list
+                self.queue.appendleft(req)
+                return
+
+    def _admit_whole(self, j: int, req: Request,
+                     sp: jax.profiler.TraceAnnotation) -> bool:
+        """Whole-prompt admission into slot ``j``; False when the page
+        pool cannot cover the request.  The admission path records
+        ``tokens`` and ``padded`` on ``sp``, its ``serve.admit`` span."""
+        if self._paged:
+            last = self._admit_paged(j, req, sp)
+            if last is None:
+                return False
+        else:
+            last = self._admit_contiguous(j, req, sp)
+        nonce = self._next_nonce()
+        with tracing.span("serve.admit.first_token"):
+            first = self._first_token(last, nonce)
+        self._begin_decode(j, _Slot(req=req, emitted=[], nonce=nonce), first)
+        return True
+
+    def _first_token(self, last: jax.Array, nonce: int) -> int:
+        """Token 0 of a request, drawn with key (nonce, 0) from its
+        last-position prompt logits."""
+        return int(sampling.sample(
+            last, sampling.slot_keys(self.key,
+                                     jnp.asarray([nonce], jnp.int32),
+                                     jnp.zeros((1,), jnp.int32)),
+            self.engine.sampler)[0])
 
     def _claim_chunked(self, j: int, req: Request) -> bool:
         """Chunked admission claims the SLOT (and, paged, its worst-case
@@ -258,38 +273,26 @@ class ContinuousBatchingScheduler:
         An identical-prompt hit still short-circuits to decoding with no
         model call at all (the donor's pages/grids/logits are this
         request's own admission outcome)."""
-        eng = self.engine
         n_prompt = len(req.prompt)
         if not self._paged:
             # the slot may be re-used: its valid length restarts at 0 and
             # the chunk writes overwrite the stale rows front-to-back
-            self.cache = kv_cache.set_length(self.cache, j, 0)
+            with tracing.span("serve.admit.cache_write"):
+                self.cache = kv_cache.set_length(self.cache, j, 0)
             self.slots[j] = _Slot(req=req, emitted=[],
                                   nonce=self._next_nonce(),
                                   pending=list(req.prompt))
             return True
-        plan = paging.plan_admission(self.allocator, self.registry,
-                                     tuple(req.prompt), req.max_new_tokens,
-                                     quantized=eng.cache == "quantized")
+        plan = self._plan_pages(j, req)
         if plan is None:
             return False
-        self.cache = paging.set_table_rows(self.cache, j, plan.pages)
-        self._slot_pages[j] = plan.pages
-        if plan.cow_src is not None:
-            self.cache = paging.copy_pages(self.cache, plan.cow_src,
-                                           plan.fresh[0])
         nonce = self._next_nonce()
         if plan.suffix_start >= n_prompt and plan.entry is not None:
             # identical-prompt hit: no model call, no chunking to do
-            if plan.entry.k_scales is not None:
-                self.cache = paging.set_slot_k_scales(self.cache, j,
-                                                      plan.entry.k_scales)
-            self.cache = paging.set_length(self.cache, j, n_prompt)
-            first = int(sampling.sample(
-                plan.entry.last_logits[None],
-                sampling.slot_keys(self.key, jnp.asarray([nonce], jnp.int32),
-                                   jnp.zeros((1,), jnp.int32)),
-                eng.sampler)[0])
+            self._admit_identical(j, plan, n_prompt)
+            with tracing.span("serve.admit.first_token"):
+                first = self._first_token(plan.entry.last_logits[None],
+                                          nonce)
             self._begin_decode(j, _Slot(req=req, emitted=[], nonce=nonce),
                                first)
             return True
@@ -297,7 +300,8 @@ class ContinuousBatchingScheduler:
         # chunks through the model, attending over the shared prefix
         # pages; miss: the whole prompt chunks from position 0 and the
         # prefix registers at completion (slot.plan)
-        self.cache = paging.set_length(self.cache, j, plan.suffix_start)
+        with tracing.span("serve.admit.cache_write"):
+            self.cache = paging.set_length(self.cache, j, plan.suffix_start)
         self.slots[j] = _Slot(
             req=req, emitted=[], nonce=nonce,
             pending=list(req.prompt[plan.suffix_start:]),
@@ -309,7 +313,8 @@ class ContinuousBatchingScheduler:
         past ``cap`` (the written rows must fit the slot window)."""
         return min(-(-n // self.prompt_bucket) * self.prompt_bucket, cap)
 
-    def _admit_contiguous(self, j: int, req: Request) -> jax.Array:
+    def _admit_contiguous(self, j: int, req: Request,
+                          sp: jax.profiler.TraceAnnotation) -> jax.Array:
         n_prompt = len(req.prompt)
         # pad the lone prompt to a bucket so single-request prefill
         # compiles once per bucket, not once per prompt length; never
@@ -321,17 +326,55 @@ class ContinuousBatchingScheduler:
             pad = n_prompt
         else:
             pad = self._bucket_pad(n_prompt, self.engine.max_seq)
+        sp.set_metadata(tokens=n_prompt, padded=pad)
         toks = np.zeros((1, pad), np.int32)
         toks[0, :n_prompt] = np.asarray(req.prompt, np.int32)
-        last, pre = self.engine.prefill(
-            jnp.asarray(toks), jnp.asarray([n_prompt], jnp.int32))
-        self.cache = kv_cache.write_slot(self.cache, pre, j, n_prompt,
-                                         self._batch_axes)
+        with tracing.span("serve.admit.prefill"):
+            last, pre = self.engine.prefill(
+                jnp.asarray(toks), jnp.asarray([n_prompt], jnp.int32))
+        with tracing.span("serve.admit.cache_write"):
+            self.cache = kv_cache.write_slot(self.cache, pre, j, n_prompt,
+                                             self._batch_axes)
         self.clock += pad               # whole-prompt prefill: every other
                                         # slot stalls for the padded prompt
         return last
 
-    def _admit_paged(self, j: int, req: Request) -> Optional[jax.Array]:
+    def _plan_pages(self, j: int,
+                    req: Request) -> Optional[paging.AdmitPlan]:
+        """Claim slot ``j``'s worst-case pages (sharing any registered
+        prefix) and map them into its table row; None when the pool
+        cannot cover the request (caller defers)."""
+        with tracing.span("serve.admit.plan"):
+            plan = paging.plan_admission(
+                self.allocator, self.registry, tuple(req.prompt),
+                req.max_new_tokens,
+                quantized=self.engine.cache == "quantized")
+            if plan is None:
+                return None
+            self.cache = paging.set_table_rows(self.cache, j, plan.pages)
+            self._slot_pages[j] = plan.pages
+            if plan.cow_src is not None:
+                # copy-on-write of the shared partial tail page, resolved
+                # at the moment the first divergent write is known (=
+                # admission: this slot's decode will write into that page)
+                self.cache = paging.copy_pages(self.cache, plan.cow_src,
+                                               plan.fresh[0])
+        return plan
+
+    def _admit_identical(self, j: int, plan: paging.AdmitPlan,
+                         n_prompt: int) -> None:
+        """Identical-prompt hit: the donor's pages, K grids and
+        last-position logits ARE what this request's own prefill would
+        produce — no model call at all."""
+        with tracing.span("serve.admit.cache_write"):
+            if plan.entry.k_scales is not None:
+                self.cache = paging.set_slot_k_scales(self.cache, j,
+                                                      plan.entry.k_scales)
+            self.cache = paging.set_length(self.cache, j, n_prompt)
+
+    def _admit_paged(self, j: int, req: Request,
+                     sp: jax.profiler.TraceAnnotation
+                     ) -> Optional[jax.Array]:
         """Map pages (sharing any registered prefix), prefill only what
         the mapping does not already cover, register the new prefix.
         Returns the last-valid prompt logits, or None when the pool
@@ -340,59 +383,53 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         page = eng.page_size
         n_prompt = len(req.prompt)
-        quantized = eng.cache == "quantized"
-        plan = paging.plan_admission(self.allocator, self.registry,
-                                     tuple(req.prompt), req.max_new_tokens,
-                                     quantized=quantized)
+        plan = self._plan_pages(j, req)
         if plan is None:
             return None
-        self.cache = paging.set_table_rows(self.cache, j, plan.pages)
-        self._slot_pages[j] = plan.pages
-        if plan.cow_src is not None:
-            # copy-on-write of the shared partial tail page, resolved at
-            # the moment the first divergent write is known (= admission:
-            # this slot's decode will write into that page)
-            self.cache = paging.copy_pages(self.cache, plan.cow_src,
-                                           plan.fresh[0])
         if plan.suffix_start >= n_prompt and plan.entry is not None:
-            # identical-prompt hit: the donor's pages, K grids and
-            # last-position logits ARE what this request's own prefill
-            # would produce — no model call at all
-            if plan.entry.k_scales is not None:
-                self.cache = paging.set_slot_k_scales(self.cache, j,
-                                                      plan.entry.k_scales)
-            last = plan.entry.last_logits[None]
-        elif plan.suffix_start > 0:
+            sp.set_metadata(tokens=0, padded=0)
+            self._admit_identical(j, plan, n_prompt)
+            return plan.entry.last_logits[None]
+        if plan.suffix_start > 0:
             # page-aligned prefix hit (full-dtype cache): prefill only the
             # unshared suffix, attending over the shared prefix pages
             suffix = list(req.prompt[plan.suffix_start:])
             pad = self._bucket_pad(len(suffix),
                                    eng.max_seq - plan.suffix_start)
+            sp.set_metadata(tokens=len(suffix), padded=pad)
             toks = np.zeros((1, pad), np.int32)
             toks[0, :len(suffix)] = np.asarray(suffix, np.int32)
-            last, suf = eng.prefill_suffix(jnp.asarray(toks), len(suffix),
-                                           plan.suffix_start, self.cache, j)
+            with tracing.span("serve.admit.prefill"):
+                last, suf = eng.prefill_suffix(jnp.asarray(toks),
+                                               len(suffix),
+                                               plan.suffix_start,
+                                               self.cache, j)
             self.clock += pad
             start_page = plan.suffix_start // page
             phys = plan.pages[start_page:
                               start_page + kvq.page_count(pad, page)]
-            self.cache = paging.write_slot_pages(self.cache, suf, j,
-                                                 len(suffix),
-                                                 plan.suffix_start, phys)
-        else:
-            # miss: full prefill, exactly the contiguous admission math
-            pad = self._bucket_pad(n_prompt, eng.max_seq)
-            toks = np.zeros((1, pad), np.int32)
-            toks[0, :n_prompt] = np.asarray(req.prompt, np.int32)
+            with tracing.span("serve.admit.cache_write"):
+                self.cache = paging.write_slot_pages(self.cache, suf, j,
+                                                     len(suffix),
+                                                     plan.suffix_start, phys)
+                self.cache = paging.set_length(self.cache, j, n_prompt)
+            return last
+        # miss: full prefill, exactly the contiguous admission math
+        pad = self._bucket_pad(n_prompt, eng.max_seq)
+        sp.set_metadata(tokens=n_prompt, padded=pad)
+        toks = np.zeros((1, pad), np.int32)
+        toks[0, :n_prompt] = np.asarray(req.prompt, np.int32)
+        with tracing.span("serve.admit.prefill"):
             last, pre = eng.prefill(jnp.asarray(toks),
                                     jnp.asarray([n_prompt], jnp.int32))
-            self.clock += pad
-            n_write = min(kvq.page_count(pad, page), len(plan.pages))
+        self.clock += pad
+        n_write = min(kvq.page_count(pad, page), len(plan.pages))
+        with tracing.span("serve.admit.cache_write"):
             self.cache = paging.write_slot_pages(self.cache, pre, j,
                                                  n_prompt, 0,
                                                  plan.pages[:n_write])
             self._register_prefix(j, req, plan, last)
-        self.cache = paging.set_length(self.cache, j, n_prompt)
+            self.cache = paging.set_length(self.cache, j, n_prompt)
         return last
 
     def _register_prefix(self, j: int, req: Request, plan: paging.AdmitPlan,
@@ -434,36 +471,47 @@ class ContinuousBatchingScheduler:
         while tail < remaining:
             tail *= 2
         n_steps = min(self.engine.decode_chunk, tail)
-        # per-slot sampling-key state: each live slot's admission nonce and
-        # its own generated-token count (len(emitted) — token 0 was drawn
-        # at admission).  Chunk geometry never enters the keys, so a
-        # shorter tail chunk cannot skip key indices (the old scheme
-        # folded chunk_idx * decode_chunk and silently broke
-        # scheduler-vs-solo parity for everything except greedy).
-        nonces = np.array([s.nonce if s is not None else 0
+        with tracing.span("serve.decode", live=int(active.sum()),
+                          slots=self.n_slots, steps=n_steps):
+            self._decode_round(active, n_steps)
+
+    def _decode_round(self, active: np.ndarray, n_steps: int) -> None:
+        with tracing.span("serve.decode.prepare"):
+            # per-slot sampling-key state: each live slot's admission
+            # nonce and its own generated-token count (len(emitted) —
+            # token 0 was drawn at admission).  Chunk geometry never
+            # enters the keys, so a shorter tail chunk cannot skip key
+            # indices (the old scheme folded chunk_idx * decode_chunk and
+            # silently broke scheduler-vs-solo parity for everything
+            # except greedy).
+            nonces = np.array([s.nonce if s is not None else 0
+                               for s in self.slots], np.int32)
+            t0 = np.array([len(s.emitted) if s is not None else 0
                            for s in self.slots], np.int32)
-        t0 = np.array([len(s.emitted) if s is not None else 0
-                       for s in self.slots], np.int32)
-        self.cache, tok, toks = self.engine.decode_chunk_step(
-            self.cache, jnp.asarray(self._tok), self.key, nonces=nonces,
-            step0=t0, active=jnp.asarray(active), n_steps=n_steps)
-        toks_np = np.asarray(toks)
+            feed, live = jnp.asarray(self._tok), jnp.asarray(active)
+        with tracing.span("serve.decode.dispatch"):
+            self.cache, tok, toks = self.engine.decode_chunk_step(
+                self.cache, feed, self.key, nonces=nonces, step0=t0,
+                active=live, n_steps=n_steps)
+        with tracing.span("serve.decode.sync"):
+            toks_np = np.asarray(toks)
         c0 = self.clock                 # scan step i emits at c0 + i + 1
         self.clock += n_steps
-        for j, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            done = False
-            for i, t in enumerate(toks_np[j]):
-                slot.emitted.append(int(t))
-                self._record_emit(slot.req.uid, c0 + i + 1)
-                if self._finish_reason(slot) is not None:
-                    done = True
-                    break
-            if done:
-                self._evict(slot, j)
-            else:
-                self._tok[j, 0] = slot.emitted[-1]
+        with tracing.span("serve.decode.harvest"):
+            for j, slot in enumerate(self.slots):
+                if slot is None:
+                    continue
+                done = False
+                for i, t in enumerate(toks_np[j]):
+                    slot.emitted.append(int(t))
+                    self._record_emit(slot.req.uid, c0 + i + 1)
+                    if self._finish_reason(slot) is not None:
+                        done = True
+                        break
+                if done:
+                    self._evict(slot, j)
+                else:
+                    self._tok[j, 0] = slot.emitted[-1]
 
     def _spec_round(self) -> None:
         """One speculative round for every live slot (serve/spec.py):
@@ -483,10 +531,16 @@ class ContinuousBatchingScheduler:
         with the device length watermark.
         """
         active = np.array([s is not None for s in self.slots])
+        with tracing.span("serve.spec", live=int(active.sum()),
+                          width=self.spec.k + 1):
+            self._spec_verify(active)
+
+    def _spec_verify(self, active: np.ndarray) -> None:
         d = self.spec.propose(self._tok, active)              # (B, k)
         x = np.concatenate([self._tok, d], axis=1)            # (B, k+1)
-        layers, g, _ = self.engine.verify_step(
-            self.cache, jnp.asarray(x), active=jnp.asarray(active))
+        with tracing.span("serve.spec.dispatch"):
+            layers, g, _ = self.engine.verify_step(
+                self.cache, jnp.asarray(x), active=jnp.asarray(active))
         # one verify dispatch of width k+1 (committed tokens emit as a
         # burst) PLUS the draft's k+1 propose steps priced at the draft's
         # resident-bytes/token roofline share of a target step — 0 for
@@ -496,7 +550,8 @@ class ContinuousBatchingScheduler:
         # byte ratio instead (SpecDecoder.draft_step_cost)
         self.clock += (self.spec.k + 1) * (
             1.0 + self.spec.draft_step_cost(self.cache))
-        g_np = np.asarray(g)
+        with tracing.span("serve.spec.sync"):
+            g_np = np.asarray(g)
         accepted = self.spec.accept(d, g_np, active)          # (B,) j
         self.cache = self.engine.commit_verified(
             self.cache, layers, jnp.asarray(accepted),
@@ -538,12 +593,17 @@ class ContinuousBatchingScheduler:
         logits, and decode rows sample key (nonce, t) on the same
         history — token-for-token identical to whole-prompt admission.
         """
-        eng = self.engine
-        chunk = eng.prefill_chunk
+        chunk = self.engine.prefill_chunk
         k = self.spec.k if self.spec is not None else 0
         s_w = max(chunk, k + 1) if self.spec is not None else chunk
-        n = self.n_slots
         active = np.array([s is not None for s in self.slots])
+        with tracing.span("serve.fused", live=int(active.sum()), width=s_w):
+            self._fused_dispatch(active, k, s_w)
+
+    def _fused_dispatch(self, active: np.ndarray, k: int, s_w: int) -> None:
+        eng = self.engine
+        chunk = eng.prefill_chunk
+        n = self.n_slots
         role = np.array([s is not None and bool(s.pending)
                          for s in self.slots])
         decode_mask = active & ~role
@@ -570,13 +630,15 @@ class ContinuousBatchingScheduler:
                 if d is not None:
                     tokens[j, 1:k + 1] = d[j]
                     n_valid[j] = k + 1
-        layers, staging, sampled, g, logits = eng.fused_step(
-            self.cache, jnp.asarray(tokens), n_valid, self.key,
-            nonces=nonces, t_idx=t_idx, active=jnp.asarray(active),
-            staging=self.staging,
-            role=role if self.staging is not None else None)
-        g_np = np.asarray(g)
-        sampled_np = np.asarray(sampled)
+        with tracing.span("serve.fused.dispatch"):
+            layers, staging, sampled, g, logits = eng.fused_step(
+                self.cache, jnp.asarray(tokens), n_valid, self.key,
+                nonces=nonces, t_idx=t_idx, active=jnp.asarray(active),
+                staging=self.staging,
+                role=role if self.staging is not None else None)
+        with tracing.span("serve.fused.sync"):
+            g_np = np.asarray(g)
+            sampled_np = np.asarray(sampled)
         if d is not None:
             accepted = self.spec.accept(d, g_np, decode_mask)
             steps = np.where(role, take, accepted).astype(np.int32)
