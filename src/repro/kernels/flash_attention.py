@@ -160,9 +160,9 @@ def _decode_scratch(parts: int, group: int, hkv: int, dp: int) -> list:
             pltpu.VMEM((parts, group, hkv, dp), jnp.float32)]
 
 
-def _kv_decode_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
-                      m_ref, l_ref, acc_ref, *, bs: int, ns: int, bits: int,
-                      scale: float):
+def _kv_decode_kernel(layer_ref, pos_ref, q_ref, kq_ref, ks_ref, vq_ref,
+                      vs_ref, o_ref, m_ref, l_ref, acc_ref, *, bs: int,
+                      ns: int, bits: int, scale: float):
     j = pl.program_id(1)          # kv block (innermost)
 
     @pl.when(j == 0)
@@ -177,8 +177,9 @@ def _kv_decode_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
     @pl.when(j * bs <= pos)
     def _step():
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
-        _decode_block(q_ref, kq_ref, ks_ref, vq_ref, vs_ref[0], kpos <= pos,
-                      m_ref, l_ref, acc_ref, bits=bits, scale=scale)
+        _decode_block(q_ref, kq_ref, ks_ref, vq_ref, vs_ref[0].T,
+                      kpos <= pos, m_ref, l_ref, acc_ref, bits=bits,
+                      scale=scale)
 
     @pl.when(j == ns - 1)
     def _finish():
@@ -188,45 +189,68 @@ def _kv_decode_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("bits", "bs", "interpret"))
 def kv_decode_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
                         vq: jax.Array, v_scale: jax.Array,
-                        positions: jax.Array, bits: int = 8, bs: int = 128,
+                        positions: jax.Array, layer: jax.Array | None = None,
+                        bits: int = 8, bs: int = 128,
                         interpret: bool = False) -> jax.Array:
     """Fused dequant decode attention over a quantized KV cache.
 
     q: (B, H, D) — one query token per request.
-    kq/vq: (B, S, Hkv, D) int8 or (B, S, Hkv, D//2) packed-int4 uint8.
-    k_scale: (B, Hkv, D) f32 per-channel; v_scale: (B, S, Hkv) f32
-    per-token; positions: (B,) int32 — rows with s_pos <= positions[b] are
-    attended (the serving validity mask).  Returns (B, H, D) f32.
+    kq/vq: (L, B, S, Hkv, D) int8 or (L, B, S, Hkv, D//2) packed-int4
+    uint8 — a stack of layers, of which layer ``layer`` (an int32 scalar)
+    is read; v_scale: (L, B, S, Hkv) f32 per-token, stacked the same way.
+    An unstacked cache ((B, S, Hkv, ...) codes, (B, S, Hkv) scales, no
+    ``layer``) is read as a stack of one.  k_scale: (B, Hkv, D) f32
+    per-channel, this layer's; positions: (B,) int32 — rows with s_pos <=
+    positions[b] are attended (the serving validity mask).  Returns
+    (B, H, D) f32.
 
     Grid (B, ns), S innermost, all heads per step; K/V code tiles
     dequantize in-register right before use, so HBM only ever streams the
-    1-byte (or half-byte) codes.  D is deliberately NOT blocked (head_dim
-    is small), so only S must divide ``bs`` — the dispatch layer
-    (kernels/ops) picks a divisor for non-tile-multiple S.
+    1-byte (or half-byte) codes.  The layer rides in as a scalar-prefetch
+    operand and the code and scale index maps select it, so a decode step
+    inside the layer scan reads its layer straight out of the carried
+    stack, with no slab copied out to feed the call.  D is deliberately
+    NOT blocked (head_dim is small), so only S must divide ``bs`` — the
+    dispatch layer (kernels/ops) picks a divisor for non-tile-multiple S.
+    V scales travel S-minor, as (Hkv, bs) tiles whose lanes the rows fill
+    (XLA keeps the scale stack in that layout), so on the chip ``bs`` is
+    a multiple of 128 or all of S.
     """
+    if layer is None:
+        kq, vq, v_scale, layer = kq[None], vq[None], v_scale[None], 0
     b, h, d = q.shape
-    _, s, hkv, dp = kq.shape
+    _, _, s, hkv, dp = kq.shape
     assert h % hkv == 0, (h, hkv)
     group = h // hkv
     parts = 1 if bits == 8 else 2
     assert dp * parts == d, (kq.shape, d, bits)
     assert vq.shape == kq.shape, (vq.shape, kq.shape)
+    assert v_scale.shape == kq.shape[:4], (v_scale.shape, kq.shape)
     bs = min(bs, s)
     assert s % bs == 0, (s, bs)
     ns = s // bs
+
+    # index maps receive the grid indices, then the scalar-prefetch refs
+    # (layer, positions)
+    def rows(b, j, lyr, p):
+        return (lyr[0], b, j, 0, 0)
+
+    def slot(b, j, lyr, p):
+        return (b, 0, 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,           # positions
+        num_scalar_prefetch=2,           # layer, positions
         grid=(b, ns),
         in_specs=[
-            pl.BlockSpec((1, parts, group, hkv, dp),
-                         lambda b, j, p: (b, 0, 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, dp), lambda b, j, p: (b, j, 0, 0)),
-            pl.BlockSpec((1, parts, hkv, dp), lambda b, j, p: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, dp), lambda b, j, p: (b, j, 0, 0)),
-            pl.BlockSpec((1, bs, hkv), lambda b, j, p: (b, j, 0)),
+            pl.BlockSpec((1, parts, group, hkv, dp), slot),
+            pl.BlockSpec((pl.squeezed, 1, bs, hkv, dp), rows),
+            pl.BlockSpec((1, parts, hkv, dp),
+                         lambda b, j, lyr, p: (b, 0, 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, bs, hkv, dp), rows),
+            pl.BlockSpec((pl.squeezed, 1, hkv, bs),
+                         lambda b, j, lyr, p: (lyr[0], b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, parts, group, hkv, dp),
-                               lambda b, j, p: (b, 0, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, parts, group, hkv, dp), slot),
         scratch_shapes=_decode_scratch(parts, group, hkv, dp),
     )
     out = pl.pallas_call(
@@ -236,8 +260,10 @@ def kv_decode_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, parts, group, hkv, dp),
                                        jnp.float32),
         interpret=interpret,
-    )(positions.astype(jnp.int32), _q_parts(q, hkv, parts), kq,
-      jnp.moveaxis(_to_parts(k_scale, parts), 0, 1), vq, v_scale)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      positions.astype(jnp.int32), _q_parts(q, hkv, parts), kq,
+      jnp.moveaxis(_to_parts(k_scale, parts), 0, 1), vq,
+      jnp.swapaxes(v_scale, -1, -2))
     return _out_from_parts(out)
 
 

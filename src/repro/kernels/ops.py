@@ -179,7 +179,8 @@ def _largest_divisor(dim: int, cap: int) -> int:
 def kv_cache_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
                        vq: jax.Array, v_scale: jax.Array,
                        positions: jax.Array, bits: int,
-                       impl: str = "auto", **kw) -> jax.Array:
+                       impl: str = "auto", layer: jax.Array | None = None,
+                       **kw) -> jax.Array:
     """Decode attention over a quantized KV cache (serving read path).
 
     Dispatch (DESIGN.md §3): the Pallas kernel on TPU dequantizes K/V code
@@ -188,6 +189,10 @@ def kv_cache_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
     full-dtype decode math, so quantized-cache serving differs from the
     full cache only by the quantization error.
 
+    ``layer``: with it, kq/vq/v_scale are a carried (L, B, ...) layer
+    stack and layer ``layer`` is read by index (k_scale is that layer's);
+    without it they are one layer's (B, ...) buffers.
+
     S_max need not be tile-aligned: the Pallas path shrinks the S block to
     the largest divisor <= 128 (same rule as ``packed_matmul``), and D is
     never blocked.
@@ -195,11 +200,11 @@ def kv_cache_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
     impl = _resolve(impl)
     if impl == "ref":
         return ref.kv_cache_attention(q, kq, k_scale, vq, v_scale,
-                                      positions, bits)
+                                      positions, bits, layer)
     if "bs" not in kw:
-        kw["bs"] = _largest_divisor(kq.shape[1], 128)
+        kw["bs"] = _largest_divisor(kq.shape[-3], 128)
     return _flash.kv_decode_attention(q, kq, k_scale, vq, v_scale, positions,
-                                      bits=bits,
+                                      layer, bits=bits,
                                       interpret=(impl == "interpret"), **kw)
 
 

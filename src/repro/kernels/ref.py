@@ -133,7 +133,8 @@ def pack_w2(codes: jax.Array) -> jax.Array:
 # ------------------------------------------------------- kv-cache attention
 def kv_cache_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
                        vq: jax.Array, v_scale: jax.Array,
-                       positions: jax.Array, bits: int) -> jax.Array:
+                       positions: jax.Array, bits: int,
+                       layer: jax.Array | None = None) -> jax.Array:
     """Decode attention over a QUANTIZED KV cache — the pure-jnp oracle of
     kernels/flash_attention.kv_decode_attention, and the production CPU
     serving path (kernels/ops dispatch, impl='auto' off-TPU).
@@ -147,8 +148,12 @@ def kv_cache_attention(q: jax.Array, kq: jax.Array, k_scale: jax.Array,
 
     q: (B, H, D); kq/vq: (B, S, Hkv, D or D//2) int8/uint8 codes;
     k_scale: (B, Hkv, D); v_scale: (B, S, Hkv); positions: (B,) int32.
-    Returns (B, H, D) f32.
+    With ``layer``, kq/vq/v_scale are (L, B, ...) layer stacks read at
+    that layer, as the kernel reads them.  Returns (B, H, D) f32.
     """
+    if layer is not None:
+        kq, vq, v_scale = (jax.lax.dynamic_index_in_dim(a, layer, 0, False)
+                           for a in (kq, vq, v_scale))
     k = kv_quant.dequant_k(kq, k_scale, bits)            # (B,S,Hkv,D) f32
     v = kv_quant.dequant_v(vq, v_scale, bits)
     h, d = q.shape[1], q.shape[2]

@@ -71,23 +71,48 @@ def chunked_attention(q: jax.Array,
 
 
 def cache_write(cache_arr: jax.Array, new: jax.Array,
-                positions: jax.Array) -> jax.Array:
-    """Write decode-step entries per request into a (B, S_max, ...) cache.
+                positions: jax.Array,
+                layer: Optional[jax.Array] = None) -> jax.Array:
+    """Write decode-step entries per request into a (B, S_max, ...) cache,
+    or, with ``layer``, into layer ``layer`` of an (L, B, S_max, ...)
+    stack.
 
     new: (B, S, ...) — S consecutive K/V rows per request (S == 1 for the
     scanned decode step, S == k+1 for a speculative verify dispatch);
     positions: (B, S) absolute write positions, PER REQUEST (continuous
     batching slots requests with unequal prompt lengths into one batch, so
     there is no shared scalar position).  Implemented as a batched row
-    scatter (O(B·S·H·D) traffic, in-place inside a scan carry) rather
-    than a one-hot select over the whole buffer; ``mode='drop'`` makes
-    out-of-range positions (>= S_max, e.g. an evicted slot that ran past
-    its window) write nothing.  Positions within a request are distinct,
-    so the multi-row scatter is bit-identical to S sequential writes.
+    scatter (O(B·S·H·D) traffic) rather than a one-hot select over the
+    whole buffer.  Inside the decode layer scan the stack is the scan's
+    carry and the rows land at ``[layer, b, pos]``, so XLA updates the
+    carried buffer in place and no layer slab is sliced out or written
+    back.  ``mode='drop'`` makes out-of-range positions (>= S_max, e.g. an
+    evicted slot that ran past its window) write nothing.  Positions
+    within a request are distinct, so the multi-row scatter is
+    bit-identical to S sequential writes.
     """
-    b = cache_arr.shape[0]
-    return cache_arr.at[jnp.arange(b)[:, None], positions].set(
-        new.astype(cache_arr.dtype), mode="drop")
+    b = new.shape[0]
+    rows = (jnp.arange(b)[:, None], positions)
+    if layer is not None:
+        rows = (layer,) + rows
+    return cache_arr.at[rows].set(new.astype(cache_arr.dtype), mode="drop")
+
+
+def reads_by_layer(cache) -> bool:
+    """Whether ``gqa_apply`` takes this decode cache as a carried layer
+    stack plus its ``layer`` index: the contiguous GQA caches (full-dtype
+    ``k``/``v``, quantized ``kq``/``vq``), whose rows are written in place
+    at ``[layer, b, pos]`` and whose layer the attention reads by index.
+    Paged pools, MLA latents and recurrent state are sliced out of the
+    stack per layer and written back."""
+    return isinstance(cache, dict) and ("k" in cache or "kq" in cache)
+
+
+def _at(a: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
+    """``a`` at this block's layer: read by index from a carried (L, ...)
+    stack when ``layer`` is set, else ``a`` itself."""
+    return a if layer is None else jax.lax.dynamic_index_in_dim(
+        a, layer, 0, keepdims=False)
 
 
 def _repeat_kv(x: jax.Array, group: int) -> jax.Array:
@@ -263,32 +288,37 @@ def gqa_apply(p, x, bits, cfg, mode: str, cache, positions,
         # per-channel grid, V with its own exact row scale) and attention
         # reads the codes through the fused dequant kernel — a
         # full-precision cache is never materialized in HBM.
+        # ``layer`` set: the leaves are the layer scan's carried stacks
+        # (transformer.apply), written at [layer, b, pos] and read by index
         cbits = kvq.cache_bits(cache)
-        role = cache.get("role")
+        layer = cache.get("layer")
+        k_scale = _at(cache["k_scale"], layer)
+        role = _at(cache["role"], layer) if "role" in cache else None
         if role is not None:
             # fused chunked-prefill dispatch — same staging contract as
             # the paged quant branch above: prefilling rows suppress
             # their quant writes (pos >= S_max drops in cache_write) and
             # run full-dtype through the staging buffers instead.
             main_pos = jnp.where(role[:, None],
-                                 jnp.int32(cache["kq"].shape[1]), positions)
+                                 jnp.int32(cache["kq"].shape[-3]), positions)
             stage_pos = jnp.where(role[:, None], positions,
-                                  jnp.int32(cache["sk"].shape[1]))
-            sk = cache_write(cache["sk"], k, stage_pos)
-            sv = cache_write(cache["sv"], v, stage_pos)
-            staged = _dense_decode_attention(q, sk, sv, positions, group)
+                                  jnp.int32(cache["sk"].shape[-3]))
+            sk = cache_write(cache["sk"], k, stage_pos, layer)
+            sv = cache_write(cache["sv"], v, stage_pos, layer)
+            staged = _dense_decode_attention(q, _at(sk, layer),
+                                             _at(sv, layer), positions, group)
         else:
             main_pos = positions
-        kq_new = kvq.quantize_k(k, cache["k_scale"], cbits)
+        kq_new = kvq.quantize_k(k, k_scale, cbits)
         vs_new = kvq.v_token_scale(v, cbits)
         vq_new = kvq.quantize_v(v, vs_new, cbits)
-        ck = cache_write(cache["kq"], kq_new, main_pos)
-        cv = cache_write(cache["vq"], vq_new, main_pos)
-        cvs = cache_write(cache["v_scale"], vs_new, main_pos)
+        ck = cache_write(cache["kq"], kq_new, main_pos, layer)
+        cv = cache_write(cache["vq"], vq_new, main_pos, layer)
+        cvs = cache_write(cache["v_scale"], vs_new, main_pos, layer)
         if s == 1 and role is None:
-            out = kops.kv_cache_attention(q[:, 0], ck, cache["k_scale"],
-                                          cv, cvs, positions[:, 0],
-                                          cbits)[:, None]
+            out = kops.kv_cache_attention(q[:, 0], ck, k_scale, cv, cvs,
+                                          positions[:, 0], cbits,
+                                          layer=layer)[:, None]
         else:
             # Speculative verify (S = k+1): batched writes are
             # byte-identical to sequential writes (K quantizes against
@@ -297,9 +327,9 @@ def gqa_apply(p, x, bits, cfg, mode: str, cache, positions,
             # own mask — bit-exact per position vs sequential decode.
             # impl='ref': no multi-query Pallas kernel yet (future work).
             def _att(qi, pi):
-                return kops.kv_cache_attention(qi, ck, cache["k_scale"],
-                                               cv, cvs, pi, cbits,
-                                               impl="ref")
+                return kops.kv_cache_attention(qi, ck, k_scale, cv, cvs, pi,
+                                               cbits, impl="ref",
+                                               layer=layer)
             out = jax.vmap(_att, in_axes=(1, 1), out_axes=1)(q, positions)
         if role is not None:
             out = jnp.where(role[:, None, None, None],
@@ -309,7 +339,7 @@ def gqa_apply(p, x, bits, cfg, mode: str, cache, positions,
         new = {"kq": ck, "k_scale": cache["k_scale"],
                "vq": cv, "v_scale": cvs}
         if role is not None:
-            new.update(sk=sk, sv=sv, role=role)
+            new.update(sk=sk, sv=sv, role=cache["role"])
         return y, new
 
     if mode == "decode":
@@ -319,9 +349,11 @@ def gqa_apply(p, x, bits, cfg, mode: str, cache, positions,
         # speculative verify dispatch, where the per-query mask below
         # gives each draft position exactly the prefix a sequential
         # decode would have seen.
-        ck = cache_write(cache["k"], k, positions)
-        cv = cache_write(cache["v"], v, positions)
-        out = _dense_decode_attention(q, ck, cv, positions, group)
+        layer = cache.get("layer")
+        ck = cache_write(cache["k"], k, positions, layer)
+        cv = cache_write(cache["v"], v, positions, layer)
+        out = _dense_decode_attention(q, _at(ck, layer), _at(cv, layer),
+                                      positions, group)
         out = out.astype(x.dtype).reshape(b, s, h * dh)
         y = qproj(out, p["wo"], bits["attn_wo"])
         return y, {"k": ck, "v": cv}

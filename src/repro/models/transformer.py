@@ -412,6 +412,47 @@ def prequantize_params(params, policy_arrays, cfg):
     return walk(params, ())
 
 
+def _layer_view(stack, i):
+    """Layer ``i`` of one block's carried decode-cache stack, as the block
+    reads it: a contiguous GQA cache stays the stack, tagged with its
+    ``layer`` (attention.reads_by_layer); any other cache kind is sliced
+    out."""
+    if stack is None:
+        return None
+    if attn.reads_by_layer(stack):
+        return dict(stack, layer=i)
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        stack)
+
+
+def _layer_store(stack, new, i):
+    """The carried stack after layer ``i`` wrote ``new`` (what the block
+    returned for ``_layer_view(stack, i)``): a contiguous GQA block hands
+    back the stack it wrote in place; a sliced layer is written back."""
+    if stack is None:
+        return None
+    if attn.reads_by_layer(stack):
+        return new
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+        stack, new)
+
+
+def decode_writes_in_place(caches) -> bool:
+    """Whether a decode-mode ``apply`` over ``caches`` writes each layer's
+    new rows in place: every stacked (scanned) block cache is a contiguous
+    GQA cache, so no layer is sliced out and written back.  Unstacked
+    per-layer caches (prefix blocks, an unrolled list) are their own
+    buffers and never sliced."""
+    pat = (caches or {}).get("pat")
+    if pat is None or isinstance(pat, (list, tuple)):
+        return True
+    runs = pat.buckets if isinstance(pat, LayerBuckets) else (pat,)
+    return all(c is None or attn.reads_by_layer(c)
+               for run in runs for c in run.values())
+
+
 def apply(params, policy_arrays, batch: Dict, cfg, ctx, mode: str = "train",
           caches: Optional[dict] = None, positions=None, tp_axis=None):
     """Returns (logits, new_caches, aux_loss).
@@ -519,12 +560,35 @@ def apply(params, policy_arrays, batch: Dict, cfg, ctx, mode: str = "train",
                     layer_params, layer_bits, layer_cache, xx, aux_c)
                 return (xx, aux_c), out_cache
 
+            def carried_body(carry, xs):
+                # decode: the run's cache stack rides in the carry with
+                # the layer index, so each layer writes only its new rows
+                # in place and reads its layer by index (_layer_view)
+                xx, aux_c, stack, i = carry
+                layer_params, layer_bits = xs
+                view = {n: _layer_view(c, i) for n, c in stack.items()}
+                xx, out_cache, aux_c = pattern_step(
+                    layer_params, layer_bits, view, xx, aux_c)
+                stack = {n: _layer_store(c, out_cache[n], i)
+                         for n, c in stack.items()}
+                return (xx, aux_c, stack, i + 1), None
+
             body_fn = jax.checkpoint(body) if mode == "train" else body
+
+            def run(bp, bb, bc, xx, aux_c):
+                """Scan one stacked run of layers -> (x, aux, cache)."""
+                if mode == "decode" and bc is not None:
+                    (xx, aux_c, bc, _), _ = jax.lax.scan(
+                        carried_body, (xx, aux_c, bc, jnp.int32(0)),
+                        (bp, bb))
+                    return xx, aux_c, bc
+                (xx, aux_c), cs = jax.lax.scan(body_fn, (xx, aux_c),
+                                               (bp, bb, bc))
+                return xx, aux_c, cs
+
             if lay.kind == "stacked":
-                xs = (params["pat"], pat_bits, pat_caches)
-                (x, aux_total), cache_stack = jax.lax.scan(
-                    body_fn, (x, aux_total), xs)
-                new_caches["pat"] = cache_stack
+                x, aux_total, new_caches["pat"] = run(
+                    params["pat"], pat_bits, pat_caches, x, aux_total)
             else:
                 # Bucketed (DESIGN.md §3): python-step only across
                 # signature boundaries, lax.scan within each contiguous
@@ -544,8 +608,7 @@ def apply(params, policy_arrays, batch: Dict, cfg, ctx, mode: str = "train",
                         bc = pat_caches.buckets[bi]
                     else:
                         bc = _slice(pat_caches)
-                    (x, aux_total), cs = jax.lax.scan(
-                        body_fn, (x, aux_total), (bp, bb, bc))
+                    x, aux_total, cs = run(bp, bb, bc, x, aux_total)
                     out_buckets.append(cs)
                     start += m
                 new_caches["pat"] = LayerBuckets(tuple(out_buckets),

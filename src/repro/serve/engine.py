@@ -30,7 +30,14 @@ measure slower than fake_quant.
     logits, so a batch never needs a shared prompt length.
   * decode  — a ``jax.lax.scan`` over a fixed chunk of steps: decoding N
     tokens is one dispatch, not N (the per-token Python loop paid one
-    dispatch + argmax sync per token).
+    dispatch + argmax sync per token).  The cache rides in the scan's
+    carry, and inside each step's layer scan too: a contiguous GQA cache
+    gets only its new rows written, in place at [layer, b, pos], and the
+    decode-attention kernel reads its layer out of the carried stack by
+    index, so no step copies the cache.  The dispatch does not donate its
+    input cache: ``decode_chunk_step`` returns a new cache and leaves the
+    one it was given readable, which costs one copy of the cache per
+    dispatch (not per step).
   * the KV cache (serve/kv_cache.py) is preallocated (B, S_max) with
     explicit valid-length tracking.  ``cache="full"`` (default) holds it
     in the COMPUTE dtype — holding it in bf16 (cfg.cache_dtype) made
@@ -596,6 +603,13 @@ class ServeEngine:
         folded, so a trajectory is invariant to decode_chunk, to the
         scheduler's shorter tail chunks, and to when the request was
         admitted relative to its batchmates.
+
+        The cache is the token scan's carry, and ``tf.apply`` carries
+        each run's layer stack through its layer scan in turn: contiguous
+        GQA caches are written in place, one row per slot and layer, and
+        read by layer index (``tf.decode_writes_in_place``; other cache
+        kinds slice their layer out and write it back).  ``layers`` is not
+        donated (see the module docstring).
 
         On the CPU/ref path, packed weights are dequantized ONCE here —
         per dispatch, before the scan — instead of once per token

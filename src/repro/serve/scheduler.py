@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import kv_quant as kvq
+from repro.models import transformer as tf
 from repro.serve import kv_cache, paging, sampling, tracing
 from repro.serve import spec as spec_mod
 from repro.serve.engine import ServeEngine
@@ -472,7 +473,9 @@ class ContinuousBatchingScheduler:
             tail *= 2
         n_steps = min(self.engine.decode_chunk, tail)
         with tracing.span("serve.decode", live=int(active.sum()),
-                          slots=self.n_slots, steps=n_steps):
+                          slots=self.n_slots, steps=n_steps,
+                          inplace=int(tf.decode_writes_in_place(
+                              self.cache.layers))):
             self._decode_round(active, n_steps)
 
     def _decode_round(self, active: np.ndarray, n_steps: int) -> None:

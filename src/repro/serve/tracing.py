@@ -22,7 +22,10 @@ Spans, nested on the scheduler's thread:
     serve.admit.cache_write  the slot's cache rows, length and prefix entry
     serve.admit.first_token  the first token's sample and its host sync
   serve.decode             one per scanned decode round; ``live`` slots,
-                           ``slots``, scan ``steps``
+                           ``slots``, scan ``steps``, ``inplace`` (1 when
+                           the cache's rows are written in place inside
+                           the layer scan, 0 when its kind is sliced per
+                           layer and written back)
     serve.decode.prepare     sampling-key state and argument uploads
     serve.decode.dispatch    the decode program's dispatch
     serve.decode.sync        the wait for the round's tokens

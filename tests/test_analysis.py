@@ -165,6 +165,34 @@ def test_dtype_flow_traces_as_deployed(quantized_engine):
     assert recs, "CPU ref decode dequantizes the cache — must be visible"
 
 
+# ----------------------------------- decode cache rides in the carry
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_decode_scans_carry_the_cache(quantized_engine, backend):
+    """No scan of the decode program takes a cache buffer, or one layer
+    of one, as its xs or hands one back as its ys: the token scan and
+    every bucket's layer scan carry the cache, so a step writes its new
+    rows in place and slices no layer out (the deployed Pallas read and
+    the CPU ref read alike)."""
+    eng = quantized_engine
+    closure = eng.dispatch_closures()["decode"]
+    shapes = set()
+    for leaf in jax.tree.leaves(closure.args[2]):
+        if eng.max_seq in leaf.shape:
+            shapes |= {leaf.shape, leaf.shape[1:]}
+    assert shapes
+    with kops.deployed_backend(backend):
+        closed = closure.trace()
+    scans = [e for e in jaxpr_checks.iter_eqns(closed)
+             if e.primitive.name == "scan"]
+    # the token scan and one layer scan per bucket, at least
+    assert len(scans) >= 1 + len(eng._cache_plan)
+    for e in scans:
+        n_in = e.params["num_consts"] + e.params["num_carry"]
+        xs = {v.aval.shape for v in e.invars[n_in:]}
+        ys = {v.aval.shape for v in e.outvars[e.params["num_carry"]:]}
+        assert not shapes & (xs | ys), (shapes & (xs | ys))
+
+
 # ------------------------------------------- collectives (DESIGN §3)
 def test_sharded_decode_has_exactly_two_psums(sharded_engine):
     res = contracts.check_collectives(sharded_engine)

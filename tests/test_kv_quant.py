@@ -135,6 +135,30 @@ def test_kv_decode_attention_mask_positions(rng, pos):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(bb))
 
 
+@pytest.mark.parametrize("impl", ["interpret", "ref"])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_kv_decode_attention_reads_a_layer_stack_by_index(rng, bits, impl):
+    """Stacked (L, B, S, Hkv, Dp) codes and (L, B, S, Hkv) V scales read
+    at a layer index give, bit for bit, what the call on that layer's
+    slice gives — the decode layer scan's carried read.  Slot 2's
+    position is past S_max, as an evicted slot's is."""
+    n_layers, b, s, hkv, group, d = 3, 3, 40, 2, 2, 32
+    layers = [_quant_cache(rng, b, s, hkv, d, bits)[2]
+              for _ in range(n_layers)]
+    stack = {k: jnp.stack([c[k] for c in layers])
+             for k in ("kq", "vq", "v_scale")}
+    q = jnp.asarray(rng.normal(size=(b, hkv * group, d)), jnp.float32)
+    positions = jnp.asarray([0, 23, s + 5], jnp.int32)
+    for lyr, c in enumerate(layers):
+        got = ops.kv_cache_attention(
+            q, stack["kq"], c["k_scale"], stack["vq"], stack["v_scale"],
+            positions, bits, impl=impl, layer=jnp.int32(lyr), bs=8)
+        want = ops.kv_cache_attention(
+            q, c["kq"], c["k_scale"], c["vq"], c["v_scale"], positions,
+            bits, impl=impl, bs=8)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # --------------------------------------------- paged decode attention
 def _paged_cache(rng, b, hkv, d, bits, lengths, page, n_pages, pool_extra=2,
                  poison=None):

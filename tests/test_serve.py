@@ -1285,3 +1285,45 @@ def test_engine_spec_paged_pool_floor(setup):
                       spec=EngineSpec(cache_layout="paged", n_pages=3))
     with pytest.raises(ValueError, match="page"):
         eng.new_cache(4)
+
+
+# ------------------------------------------- decode cache ownership
+@pytest.mark.parametrize("layout", ["contiguous", "paged", "spec"])
+def test_dispatch_leaves_its_input_cache_readable(setup, layout):
+    """A decode dispatch does not donate the cache it is handed: the
+    scanned decode (contiguous and paged) and the fused verify dispatch
+    return new layers and leave every input leaf, a paged cache's block
+    table among them, alive.  A caller that kept the old cache can run
+    the same step from it again and gets the same tokens."""
+    from repro.serve import paging
+    cfg, ctx, params, policy, pa, qparams = setup
+    kw = dict(cache="quantized", cache_bits=8)
+    if layout == "paged":
+        kw.update(cache_layout="paged", page_size=16)
+    if layout == "spec":
+        kw.update(draft=DraftSpec(kind="ngram", k=3))
+    eng = ServeEngine(cfg=cfg, params=qparams, policy_arrays=pa, ctx=ctx,
+                      max_seq=64, spec=EngineSpec(**kw))
+    rng = np.random.default_rng(7)
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab, (2, 12)), jnp.int32)
+    _, pre = eng.prefill(prompt)
+    splice = (paging if layout == "paged" else kv_cache).splice_prefill
+    cache = splice(eng.new_cache(2), pre, jnp.full((2,), 12, jnp.int32))
+    tok = prompt[:, -1:]
+
+    def step():
+        if layout == "spec":
+            layers, greedy, _ = eng.verify_step(
+                cache, jnp.concatenate([tok, tok, tok, tok], axis=1))
+            return layers, greedy
+        new, _, toks = eng.decode_chunk_step(cache, tok,
+                                             jax.random.PRNGKey(0))
+        return new.layers, toks
+
+    layers, first = step()
+    jax.block_until_ready(layers)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(cache))
+    if layout == "paged":
+        assert not cache.block_tbl.is_deleted()
+    _, again = step()
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(first))

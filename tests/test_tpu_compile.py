@@ -18,6 +18,7 @@ from repro.kernels import entropy_hist, flash_attention, quant_matmul
 
 M = 8                                       # decode batch (serving slots)
 B, H, D, S, PAGE = 8, 16, 128, 2048, 16     # olmo-1b decode attention
+LAYERS = 16
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +75,20 @@ def test_kv_decode_attention_compiles(one_chip, bits):
 
     _compile(fn, one_chip, ((B, H, D), jnp.float32), kq, ks, vq, vs,
              ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_kv_decode_attention_compiles_on_a_layer_stack(one_chip, bits):
+    """The decode layer scan's read: olmo-1b's 16-layer stack, one layer
+    selected by a scalar-prefetched index."""
+    kq, ks, vq, vs = _cache_shapes((LAYERS, B, S), bits)
+
+    def fn(q, kq, ks, vq, vs, pos, layer):
+        return flash_attention.kv_decode_attention(q, kq, ks, vq, vs, pos,
+                                                   layer, bits=bits)
+
+    _compile(fn, one_chip, ((B, H, D), jnp.float32), kq, ks, vq, vs,
+             ((B,), jnp.int32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("bits", [8, 4])

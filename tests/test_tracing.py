@@ -145,6 +145,26 @@ def test_whole_prompt_admission_and_decode_spans(setup, tmp_path, layout):
     _check_decode([r for r in roots if r["name"] == "serve.decode"])
 
 
+@pytest.mark.parametrize("layout,inplace", [("contiguous", 1),
+                                            ("paged", 0)])
+def test_decode_spans_count_in_place_dispatches(setup, tmp_path, layout,
+                                                inplace):
+    """``serve.decode``'s ``inplace`` field: 1 on every dispatch over a
+    contiguous int8 cache, whose rows the layer scan writes in place; 0
+    over paged pools, which are sliced per layer and written back.  So
+    the field sums to the count of dispatches that took the in-place
+    path."""
+    cfg = setup[0]
+    want, got, roots = _traced(
+        _engine(setup, cache="quantized", cache_bits=8, cache_layout=layout,
+                page_size=BUCKET), _requests(cfg), tmp_path)
+    assert got == want
+    rounds = [r for r in roots if r["name"] == "serve.decode"]
+    _check_decode(rounds)
+    assert sum(r["stats"]["inplace"] for r in rounds) \
+        == inplace * len(rounds)
+
+
 def test_paged_chunked_prefill_spans(setup, tmp_path):
     cfg = setup[0]
     reqs = _requests(cfg)[:2]
