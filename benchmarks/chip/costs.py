@@ -3,6 +3,14 @@ from the model's shapes and the traffic served -- never from a kernel's
 grid, its padding or its HLO, so a change to a kernel cannot change what
 its work is said to be.
 
+On a mesh of ``shards`` chips (a configuration's ``"mesh": {"model": n}``)
+everything is one chip's share, as chip 0 runs it: a column projection
+(q, k, v, gate, up) has N / n output channels and a row projection (o,
+down) K / n input channels, each with its whole input or output; attention
+covers hq / n query and hkv / n KV heads; the replicated LM head counts in
+full.  Against one chip's time and peak, the shares give one chip's
+roofline and MFU.  With ``shards`` 1 it is the whole model.
+
 Bytes are the least the algorithm must move: packed weight codes at the
 policy's bits plus one f32 scale per output channel; the live K/V codes
 and scales of each active slot; each input and output once, in bf16.
@@ -40,6 +48,7 @@ class Dims:
     f: int
     vocab: int
     cache_bits: int
+    shards: int = 1             # chips of the "model" mesh axis
 
     @classmethod
     def of(cls, cfg: dict) -> "Dims":
@@ -47,19 +56,30 @@ class Dims:
                    hq=cfg["num_attention_heads"],
                    hkv=cfg["num_key_value_heads"], hd=cfg["head_dim"],
                    f=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-                   cache_bits=cfg["engine"]["cache_bits"])
+                   cache_bits=cfg["engine"]["cache_bits"],
+                   shards=int(cfg.get("mesh", {}).get("model", 1)))
+
+    @property
+    def hq_chip(self) -> int:
+        return self.hq // self.shards
+
+    @property
+    def hkv_chip(self) -> int:
+        return self.hkv // self.shards
 
     def projections(self) -> List[Tuple[str, int, int]]:
-        """(policy slot, K, N) of each packed projection of one layer."""
-        q, kv = self.hq * self.hd, self.hkv * self.hd
+        """(policy slot, K, N) of each packed projection of one layer, one
+        chip's share."""
+        q, kv = self.hq_chip * self.hd, self.hkv_chip * self.hd
+        f = self.f // self.shards
         return [("attn_qkv", self.d, q), ("attn_qkv", self.d, kv),
                 ("attn_qkv", self.d, kv), ("attn_wo", q, self.d),
-                ("mlp_gateup", self.d, self.f), ("mlp_gateup", self.d, self.f),
-                ("mlp_down", self.f, self.d)]
+                ("mlp_gateup", self.d, f), ("mlp_gateup", self.d, f),
+                ("mlp_down", f, self.d)]
 
     @property
     def proj_params(self) -> int:
-        """Weights of the projections of all layers."""
+        """Weights of the projections of all layers, one chip's share."""
         return self.layers * sum(k * n for _, k, n in self.projections())
 
 
@@ -73,21 +93,23 @@ def quant_matmul(m: int, k: int, n: int, bits: int) -> Tuple[float, float]:
 def decode_attention(ctxs: Iterable[int], dims: Dims) -> Tuple[float, float]:
     """(FLOPs, bytes) of one layer's decode attention for one step: one
     query per active slot over its ``ctx`` live cache rows (int codes, an
-    f32 scale per V row, an f32 K scale per channel)."""
+    f32 scale per V row, an f32 K scale per channel), one chip's heads."""
     flops = data = 0.0
     code = dims.cache_bits / 8
+    hq, hkv = dims.hq_chip, dims.hkv_chip
     for ctx in ctxs:
-        flops += 4.0 * dims.hq * dims.hd * ctx
-        data += (2 * ctx * dims.hkv * dims.hd * code + ctx * dims.hkv * F32
-                 + dims.hkv * dims.hd * F32 + 2 * dims.hq * dims.hd * BF16)
+        flops += 4.0 * hq * dims.hd * ctx
+        data += (2 * ctx * hkv * dims.hd * code + ctx * hkv * F32
+                 + hkv * dims.hd * F32 + 2 * hq * dims.hd * BF16)
     return flops, data
 
 
 def token_flops(dims: Dims, ctx: int) -> float:
     """Model FLOPs of one decoded token attending ``ctx`` rows: the
-    projections, the LM head and attention's two products."""
+    projections, the LM head and attention's two products (one chip's
+    share; the replicated head in full)."""
     return (2.0 * dims.proj_params + 2.0 * dims.d * dims.vocab
-            + 4.0 * dims.layers * dims.hq * dims.hd * ctx)
+            + 4.0 * dims.layers * dims.hq_chip * dims.hd * ctx)
 
 
 def least_time(flops: float, data: float, peak_flops: float,

@@ -2,8 +2,11 @@
 
 Each takes the engine and scheduler ``serving.build`` made and breaks the
 decode dispatch underneath the harness: the tokens a chunk emits altered
-where they are produced, the cache handed back unchanged, or half the
-slots left uncomputed (what all-zero logits give: token 0).  Emitting the
+where they are produced, the cache handed back unchanged (a copy taken
+before the dispatch, with its sharding, so a dispatch that donates its
+input cache is caught as well), or half the slots left uncomputed (what
+all-zero logits give: token 0).  On a mesh the exchange between chips can
+also be left out (``MESH_FAULTS``).  Emitting the
 token fed in again would be no fault on a model whose greedy answer
 repeats one token, as internlm2's seeded weights do.  ``run.py
 --fault <name>`` plants one in a whole run on the chip; the tests plant
@@ -11,15 +14,17 @@ them at a size the CPU holds.  No benchmark run plants any.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
-def _wrap_decode(engine, post):
+def _wrap_decode(engine, post, keep_input=False):
     step = engine.decode_chunk_step
 
     def broken(cache, tok, key, **kw):
+        before = jax.tree.map(jnp.copy, cache) if keep_input else cache
         new, nxt, toks = step(cache, tok, key, **kw)
-        return post(cache, tok, new, nxt, toks)
+        return post(before, tok, new, nxt, toks)
 
     engine.decode_chunk_step = broken
 
@@ -31,7 +36,8 @@ def token_altered(engine, sched):
 
 
 def state_unchanged(engine, sched):
-    _wrap_decode(engine, lambda c, f, new, nxt, toks: (c, nxt, toks))
+    _wrap_decode(engine, lambda c, f, new, nxt, toks: (c, nxt, toks),
+                 keep_input=True)
 
 
 def half_batch_left_out(engine, sched):
@@ -41,5 +47,23 @@ def half_batch_left_out(engine, sched):
     _wrap_decode(engine, post)
 
 
+def exchange_left_out(engine, sched):
+    """Every psum of the engine's programs dropped while they are traced:
+    each chip keeps its partial sums (a fault only a mesh can have)."""
+    def drop(call):
+        def broken(*args, **kw):
+            psum = jax.lax.psum
+            jax.lax.psum = lambda x, *a, **k: x
+            try:
+                return call(*args, **kw)
+            finally:
+                jax.lax.psum = psum
+        return broken
+
+    engine._prefill = drop(engine._prefill)
+    engine.decode_chunk_step = drop(engine.decode_chunk_step)
+
+
 FAULTS = {f.__name__: f for f in (token_altered, state_unchanged,
                                   half_batch_left_out)}
+MESH_FAULTS = {f.__name__: f for f in (exchange_left_out,)}

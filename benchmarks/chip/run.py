@@ -78,7 +78,7 @@ class CompileLog:
 
 
 def parse(argv):
-    from benchmarks.chip.faults import FAULTS
+    from benchmarks.chip.faults import FAULTS, MESH_FAULTS
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -89,7 +89,8 @@ def parse(argv):
                     "below the configuration's) in the program's place and "
                     "judge it by the same limits; for setting limits, never "
                     "in a benchmark run")
-    ap.add_argument("--fault", choices=sorted(FAULTS), default=None,
+    ap.add_argument("--fault", choices=sorted({**FAULTS, **MESH_FAULTS}),
+                    default=None,
                     help="plant a fault in the timed path (faults.py); for "
                     "showing that correct catches it, never in a benchmark "
                     "run")
@@ -126,9 +127,9 @@ def main(argv=None) -> int:
     log(f"compilation cache: {compile_cache.configure()}")
     # every program the window runs is then in the cache after a first run
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    from benchmarks.chip.faults import FAULTS
+    from benchmarks.chip.faults import FAULTS, MESH_FAULTS
     return run_cell(cell, args, devices,
-                    checked_fault=FAULTS.get(args.fault))
+                    checked_fault={**FAULTS, **MESH_FAULTS}.get(args.fault))
 
 
 def run_cell(cell, args, devices, *, checked_fault=None) -> int:
@@ -148,13 +149,15 @@ def run_cell(cell, args, devices, *, checked_fault=None) -> int:
         f"{cfg['num_attention_heads']}/{cfg['num_key_value_heads']} heads x "
         f"{cfg['head_dim']}, d_ff {cfg['intermediate_size']}, vocab "
         f"{cfg['vocab_size']}), {cfg['n_slots']} slots x {cfg['max_seq']}, "
-        f"traffic {cell.traffic_name}, seed {args.seed}, "
+        f"{cfg['engine'].get('cache_layout', 'contiguous')} cache, mesh "
+        f"{cfg.get('mesh')}, traffic {cell.traffic_name}, seed {args.seed}, "
         f"{args.seconds:g} s")
     comp = CompileLog(jax)
     readers = workload.readers(cell.per_layer if args.trace
                                else cell.end_to_end)
 
-    engine, sched, bits = serving.build(cfg, args.seed, log)
+    engine, sched, bits = serving.build(cfg, args.seed, log, devices)
+    mesh = engine.mesh
     if checked_fault is not None:
         checked_fault(engine, sched)
     loop = serving.Loop(sched, int(cfg["check"].get("kv_layers", 0)))
@@ -229,7 +232,9 @@ def run_cell(cell, args, devices, *, checked_fault=None) -> int:
     log(f"peak_bytes_in_use {peak:,} of {pk.hbm_capacity:,}")
     device["memory_peak_bytes"] = peak
 
-    v = view.View(run=run, attempted=att, peaks=pk)
+    v = view.View(run=run, attempted=att, peaks=pk,
+                  decode_program=view.DECODE if mesh is None
+                  else view.MESH_DECODE)
     tr = None
     if tdir:
         v.trace_end = t_open + trace_s
@@ -263,7 +268,7 @@ def run_cell(cell, args, devices, *, checked_fault=None) -> int:
     del loop, sched, engine, run, v, done
     gc.collect()
     t = time.perf_counter()
-    params = weights.make(cfg, args.seed)
+    params = weights.make(cfg, args.seed, mesh)
     control = check.control_for(cfg) if args.control else None
     got = check.readings(cfg, params, bits, samples, control=control)
     del params

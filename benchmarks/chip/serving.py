@@ -3,7 +3,9 @@
 ``build`` turns the benchmark's weights into the served model through the
 program's own pipeline: EAGL gains (the Pallas histogram), the knapsack at
 the configuration's budget, ``pack_params``, and a ``ServeEngine`` behind a
-``ContinuousBatchingScheduler``.
+``ContinuousBatchingScheduler``.  A configuration that states
+``"mesh": {"model": n}`` is served tensor-parallel over the cell's ``n``
+chips: the weights are made sharded and the engine gets the mesh.
 
 ``drive`` is the open-loop client.  The scheduler only drains a queue it
 was given, so the loop here plays its ``run()`` one round at a time and
@@ -48,17 +50,39 @@ def arch_config(cfg: dict):
         param_dtype=dt, compute_dtype=dt)
 
 
-def select_policy(arch, params, pol: dict):
+def mesh_for(cfg: dict, devices):
+    """The configuration's tensor-parallel mesh over the cell's
+    ``devices``, or None when it states none."""
+    if "mesh" not in cfg:
+        return None
+    from repro.launch.mesh import make_mesh
+    if set(cfg["mesh"]) != {"model"}:
+        raise ValueError(f"mesh {cfg['mesh']}: only a 'model' axis is "
+                         "served")
+    n = int(cfg["mesh"]["model"])
+    if devices is None or n != len(devices):
+        raise ValueError(f"mesh 'model' {n} needs the cell's chips to be "
+                         f"{n}, not {0 if devices is None else len(devices)}")
+    return make_mesh((n,), ("model",), devices=devices)
+
+
+def select_policy(arch, params, pol: dict, device=None):
     """EAGL gains -> knapsack at the budget; returns the mixed policy and
-    its bits per layer for each projection group."""
+    its bits per layer for each projection group.  ``device``: where each
+    tensor is brought whole for its histogram (a Pallas kernel is not
+    partitioned over a mesh); None leaves the tensors where they are."""
     from repro.core import knapsack
     from repro.core.metrics import eagl
     from repro.models import transformer as tf
     if pol["metric"] != "eagl":
         raise ValueError(f"unknown policy metric {pol['metric']!r}")
     policy = tf.build_policy(arch, b_hi=pol["b_hi"], b_lo=pol["b_lo"])
-    gains = eagl.eagl_gains(
-        policy, lambda u, t: tf.fetch_unit_tensor(params, u, t), impl="auto")
+
+    def fetch(u, t):
+        got = tf.fetch_unit_tensor(params, u, t)
+        return got if device is None else jax.device_put(got, device)
+
+    gains = eagl.eagl_gains(policy, fetch, impl="auto")
     mixed = policy.apply_selection(
         knapsack.select_for_budget(policy, gains, pol["budget_frac"]).take)
     bits = {slot: [int(b) for b in arr]
@@ -66,19 +90,22 @@ def select_policy(arch, params, pol: dict):
     return mixed, bits
 
 
-def build(cfg: dict, seed: int, log):
-    """Weights from ``seed`` -> policy -> packed engine and scheduler.  The
-    bf16 weights are dropped before returning: only what a deployment
-    holds stays resident."""
+def build(cfg: dict, seed: int, log, devices=None):
+    """Weights from ``seed`` -> policy -> packed engine and scheduler, on
+    the cell's ``devices`` (a mesh over them when the configuration states
+    one).  The bf16 weights are dropped before returning: only what a
+    deployment holds stays resident."""
     from repro.parallel.context import local_context
     from repro.serve import (ContinuousBatchingScheduler, EngineSpec,
                              ServeEngine, pack_params)
     arch = arch_config(cfg)
+    mesh = mesh_for(cfg, devices)
     t = clock()
-    params = weights.make(cfg, seed)
+    params = weights.make(cfg, seed, mesh)
     log(f"[set-up] weights {clock() - t:.2f} s")
     t = clock()
-    mixed, bits = select_policy(arch, params, cfg["policy"])
+    mixed, bits = select_policy(arch, params, cfg["policy"],
+                                None if mesh is None else devices[0])
     log(f"[set-up] EAGL + knapsack {clock() - t:.2f} s")
     for slot, per_layer in bits.items():
         log(f"policy {slot}: per-layer bits {per_layer}")
@@ -92,7 +119,7 @@ def build(cfg: dict, seed: int, log):
         cfg=arch, params=packed,
         policy_arrays=jax.tree.map(jnp.asarray, mixed.as_arrays()),
         ctx=local_context(), max_seq=cfg["max_seq"],
-        spec=EngineSpec(**eng_cfg))
+        spec=EngineSpec(**eng_cfg, mesh=mesh))
     sched = ContinuousBatchingScheduler(engine, n_slots=cfg["n_slots"],
                                         prompt_bucket=cfg["prompt_bucket"])
     return engine, sched, bits
@@ -111,19 +138,39 @@ def n_steps_next(sched) -> int:
 
 
 CACHE_LEAVES = ("kq", "k_scale", "vq", "v_scale")
+PAGED = ("kq", "vq", "v_scale")     # pooled by page as pkq, pvq, pv_scale
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def slot_rows(layers, slot, n_layers: int) -> dict:
+@functools.partial(jax.jit, static_argnums=(2, 4))
+def slot_rows(layers, slot, n_layers: int, tbl=None, n_rows: int = 0
+              ) -> dict:
     """One slot's int8 cache codes and scales in the first ``n_layers``
     layers: kq, vq (n, S, kv heads, head dim), k_scale (n, kv heads, head
-    dim), v_scale (n, S, kv heads)."""
+    dim), v_scale (n, S, kv heads).  A paged cache is read through the
+    slot's page ids in ``tbl``, the block table as it stood while the slot
+    held the request, into the same shapes (``n_rows`` = S); an unmapped
+    page reads as zeros."""
     out = {}
     for name in CACHE_LEAVES:
+        paged = tbl is not None and name in PAGED
+        leaf_name = "p" + name if paged else name
         runs = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(
-            layers) if jax.tree_util.keystr(path).endswith(f"['{name}']")]
-        out[name] = jnp.concatenate([r[:, slot] for r in runs])[:n_layers]
+            layers) if jax.tree_util.keystr(path).endswith(f"['{leaf_name}']")]
+        if paged:
+            rows = [_pages(pool, tbl[slot], n_rows) for pool in runs]
+        else:
+            rows = [r[:, slot] for r in runs]
+        out[name] = jnp.concatenate(rows)[:n_layers]
     return out
+
+
+def _pages(pool, ids, n_rows: int):
+    """Stacked pool (L, pages, page, ...) read at page ``ids`` in order:
+    (L, n_rows, ...)."""
+    ids = jnp.where(ids < 0, pool.shape[1], ids)       # -1: unmapped
+    got = jnp.take(pool, ids, axis=1, mode="fill", fill_value=0)
+    rows = got.reshape((got.shape[0], -1) + got.shape[3:])
+    return rows[:, :n_rows]
 
 
 class Loop:
@@ -133,11 +180,13 @@ class Loop:
     ``capture``: uids whose slot's cache rows in the first
     ``capture_layers`` layers are copied out (``snapshots``) when the
     request finishes, before the slot is admitted again -- on the device,
-    with no host sync."""
+    with no host sync.  A paged slot is read through the block table as it
+    stood before the decode round whose harvest freed its pages."""
 
     def __init__(self, sched, capture_layers: int = 0):
         self.sched = sched
         self.n_slots = sched.n_slots
+        self.max_seq = sched.engine.max_seq
         self.n_drives = 0
         self.capture_layers = capture_layers
         self.capture: set = set()
@@ -235,6 +284,7 @@ class Loop:
                 ctx0 = {j: recs[u].prompt_len + before[j] - 1
                         for j, u in in_slot.items()}
                 steps = n_steps_next(sched)
+                tbl = getattr(sched.cache, "block_tbl", None)
                 t0 = clock()
                 with jax.profiler.TraceAnnotation("bench.decode_round"):
                     sched._decode_harvest()
@@ -256,7 +306,8 @@ class Loop:
                         del in_slot[j]
                         if uid in self.capture:
                             self.snapshots[uid] = slot_rows(
-                                sched.cache.layers, j, self.capture_layers)
+                                sched.cache.layers, j, self.capture_layers,
+                                tbl, self.max_seq)
                 rounds.append({"kind": "decode", "rows": rows,
                                "steps": steps, "t0": t0, "t1": t1})
             elif not waiting:
@@ -286,8 +337,9 @@ def warm_up(loop: Loop, mix: dict, cfg: dict, seed: int) -> None:
     """Run every shape this cell's traffic uses once, through ``drive``:
     a prefill at each padded prompt width the mix can produce, each decode
     scan length (the powers of two up to the chunk; a round's length is
-    set by the longest remaining budget, so one request at a time), and an
-    admission into every slot."""
+    set by the longest remaining budget, so one request at a time), an
+    admission into every slot and, with a paged cache, an admission for
+    each number of pages one can write (``traffic.page_writes``)."""
     bucket, max_seq = cfg["prompt_bucket"], cfg["max_seq"]
     widths = traffic.prompt_widths(mix, bucket, max_seq)
     chunk = cfg["engine"]["decode_chunk"]
@@ -305,6 +357,10 @@ def warm_up(loop: Loop, mix: dict, cfg: dict, seed: int) -> None:
                for i, w in enumerate(widths)]]
     phases += [[req(100 + t, shortest, 1 + t)] for t in tails]
     phases.append([req(200 + j, shortest, 2) for j in range(loop.n_slots)])
+    if cfg["engine"].get("cache_layout") == "paged":
+        pairs = traffic.page_writes(mix, bucket, max_seq,
+                                    loop.sched.engine.page_size)
+        phases.append([req(300 + i, p, o) for i, (p, o) in enumerate(pairs)])
     loop.capture.add("warm0200")
     for reqs in phases:
         loop.drive(reqs, clock(), 0.0, drain_s=3600.0, backlog=False)

@@ -114,3 +114,38 @@ def test_entry_point_refuses_a_host_without_a_tpu():
     assert out.returncode != 0
     assert out.stdout.strip() == ""
     assert "needs a TPU" in out.stderr
+
+
+@pytest.mark.parametrize("mix_name,bucket,max_seq,page", [
+    ("chat", 256, 2048, 16), ("longdoc", 256, 2048, 16),
+    ("tiny", 32, 128, 16)])
+def test_page_writes_cover_every_paged_admission_shape(mix_name, bucket,
+                                                       max_seq, page):
+    root = os.path.join(HERE, "data") if mix_name == "tiny" else workload.HERE
+    mix = workload.load_json(root, "traffic", mix_name)
+
+    def shape(p, o):              # (padded width, pages written)
+        w = min(-(-p // bucket) * bucket, max_seq)
+        return w, min(-(-w // page), -(-(p + o) // page))
+
+    want = {shape(p, o)
+            for p in range(mix["prompt"]["min"], mix["prompt"]["max"] + 1)
+            for o in range(mix["output"]["min"], mix["output"]["max"] + 1)}
+    pairs = traffic.page_writes(mix, bucket, max_seq, page)
+    assert sorted(shape(p, o) for p, o in pairs) == sorted(want)
+    for p, o in pairs:
+        assert mix["prompt"]["min"] <= p <= mix["prompt"]["max"]
+        assert mix["output"]["min"] <= o <= mix["output"]["max"]
+
+
+def test_a_mesh_must_be_the_cells_chips():
+    import jax
+    from benchmarks.chip import serving
+    one = jax.devices()[:1]
+    assert serving.mesh_for({"model": "olmo-1b"}, one) is None
+    with pytest.raises(ValueError, match="cell's chips"):
+        serving.mesh_for({"mesh": {"model": 4}}, one)
+    with pytest.raises(ValueError, match="only a 'model' axis"):
+        serving.mesh_for({"mesh": {"data": 1}}, one)
+    mesh = serving.mesh_for({"mesh": {"model": 1}}, one)
+    assert dict(mesh.shape) == {"model": 1}

@@ -1,8 +1,12 @@
 """The arithmetic of the end-to-end metrics, the kernel costs and the peaks
 table, on synthetic inputs with answers worked out by hand."""
+import dataclasses
+import json
+import os
+
 import pytest
 
-from benchmarks.chip import costs, e2e, peaks, serving
+from benchmarks.chip import costs, e2e, peaks, serving, workload
 from benchmarks.chip.metrics import output_tok_s, tpot_p95_ms
 from benchmarks.chip.view import View
 
@@ -10,6 +14,9 @@ OLMO = {"hidden_size": 2048, "num_hidden_layers": 16,
         "num_attention_heads": 16, "num_key_value_heads": 16, "head_dim": 128,
         "intermediate_size": 8192, "vocab_size": 50304,
         "engine": {"cache_bits": 8}}
+
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 
 def served(uid, due, first, last, n_out, n_done, n_in_window, admit=None):
@@ -136,3 +143,54 @@ def test_peaks_raise_on_an_unknown_device_kind():
     assert peaks.for_kind("TPU v5 lite").hbm_bytes == 819e9
     with pytest.raises(KeyError, match="no peaks"):
         peaks.for_kind("TPU v4")
+
+
+def recorded_rounds():
+    with open(os.path.join(DATA, "rounds_tiny.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("config", ["olmo-1b.mixed42.int8kv",
+                                    "internlm2-1.8b.mixed42.int8kv"])
+def test_costs_without_a_mesh_equal_the_recorded_totals(config):
+    rec = recorded_rounds()
+    case = rec["cases"][config]
+    dims = costs.Dims.of(workload.load_json(workload.HERE, "configs", config))
+    assert dims.shards == 1
+    got = costs.totals(rec["rounds"], dims, case["bits"], 197e12, 819e9)
+    assert dataclasses.asdict(got) == case["totals"]
+
+
+def test_one_chips_share_times_four_less_the_replicated_part_is_the_whole():
+    whole = costs.Dims.of(OLMO)
+    chip = costs.Dims.of(dict(OLMO, mesh={"model": 4}))
+    assert chip.shards == 4 and (chip.hq_chip, chip.hkv_chip) == (4, 4)
+    m = 32
+    for (_, k, n), (_, k4, n4) in zip(whole.projections(),
+                                       chip.projections()):
+        f1, b1 = costs.quant_matmul(m, k, n, 4)
+        f4, b4 = costs.quant_matmul(m, k4, n4, 4)
+        assert 4 * f4 == f1
+        if k4 == k:         # column: N split, the whole input read
+            assert n4 * 4 == n
+            replicated = m * k * costs.BF16
+        else:               # row: K split, scales and partial output whole
+            assert (k4 * 4, n4) == (k, n)
+            replicated = n * costs.F32 + m * n * costs.BF16
+        assert 4 * b4 - 3 * replicated == b1
+    assert 4 * chip.proj_params == whole.proj_params
+    fa, ba = costs.decode_attention([400, 1000], whole)
+    fa4, ba4 = costs.decode_attention([400, 1000], chip)
+    assert (4 * fa4, 4 * ba4) == (fa, ba)
+    head = 2 * 2048 * 50304             # the LM head, on every chip
+    assert 4 * costs.token_flops(chip, 100) - 3 * head == \
+        costs.token_flops(whole, 100)
+    rec = recorded_rounds()
+    bits = rec["cases"]["olmo-1b.mixed42.int8kv"]["bits"]
+    t1 = costs.totals(rec["rounds"], whole, bits, 197e12, 819e9)
+    t4 = costs.totals(rec["rounds"], chip, bits, 197e12, 819e9)
+    assert 4 * t4.decode_flops - 3 * head * t4.decode_tokens == \
+        pytest.approx(t1.decode_flops, rel=1e-12)
+    assert (t4.decode_tokens, t4.decode_steps, t4.prefill_tokens) == (
+        t1.decode_tokens, t1.decode_steps, t1.prefill_tokens)
+    assert t4.attn_least_s == pytest.approx(t1.attn_least_s / 4, rel=1e-12)

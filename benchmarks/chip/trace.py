@@ -15,8 +15,10 @@ What a TPU trace holds (as seen on a v5e under jax 0.9):
 Device and host events share one clock (ns from the trace's start).  The
 traced window is the harness's ``bench.window`` span.  Busy time is the
 union of the intervals of leaf ops (containers such as ``while`` excluded)
-inside the window, averaged over the chips; every idle gap is attributed to
-the harness span (other than the window) that overlaps it most.
+inside the window; it, each program's and each op's time are averaged over
+the chips, so that on a mesh they are one chip's time, as ``costs.py``
+counts one chip's work.  Every idle gap of chip 0 is attributed to the
+harness span (other than the window) that overlaps it most.
 """
 from __future__ import annotations
 
@@ -37,9 +39,9 @@ _SUFFIX_RE = re.compile(r"\.\d+$")
 class Trace:
     window_ns: Tuple[float, float]
     busy_ns: float                            # mean over chips
-    program_ns: Dict[str, float]              # per program, summed over chips
-    program_runs: Dict[str, int]
-    op_ns: Dict[str, float]                   # leaf ops by short name
+    program_ns: Dict[str, float]              # per program, mean over chips
+    program_runs: Dict[str, int]              # chip 0's
+    op_ns: Dict[str, float]                   # leaf ops by short name, mean
     gaps: List[Tuple[str, float]]             # (host span, idle ns), chip 0
     n_chips: int
 
@@ -140,7 +142,8 @@ def reduce(path: str) -> Trace:
                         name = program_name(ev.name)
                         program_ns[name] = (program_ns.get(name, 0.0)
                                             + iv[1] - iv[0])
-                        program_runs[name] = program_runs.get(name, 0) + 1
+                        if i == 0:
+                            program_runs[name] = program_runs.get(name, 0) + 1
             elif line.name == "XLA Ops":
                 for ev in line.events:
                     iv = _clip(ev.start_ns, ev.end_ns, lo, hi)
@@ -158,9 +161,12 @@ def reduce(path: str) -> Trace:
             for s, e in zip(edges[0::2], edges[1::2]):
                 if e > s:
                     gaps.append((_attribute(s, e, host, starts), e - s))
-    return Trace(window_ns=(lo, hi), busy_ns=sum(busy) / len(busy),
-                 program_ns=program_ns, program_runs=program_runs,
-                 op_ns=op_ns, gaps=gaps, n_chips=len(devices))
+    n = len(devices)
+    return Trace(window_ns=(lo, hi), busy_ns=sum(busy) / n,
+                 program_ns={k: v / n for k, v in program_ns.items()},
+                 program_runs=program_runs,
+                 op_ns={k: v / n for k, v in op_ns.items()}, gaps=gaps,
+                 n_chips=n)
 
 
 def _attribute(s: float, e: float, host, starts) -> str:

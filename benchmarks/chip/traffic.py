@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import statistics
-from typing import List
+from typing import List, Tuple
 
 import numpy as np
 
@@ -103,3 +103,32 @@ def prompt_widths(mix: dict, bucket: int, max_seq: int) -> List[int]:
     first = math.ceil(lo / bucket) * bucket
     last = math.ceil(hi / bucket) * bucket
     return sorted({min(w, max_seq) for w in range(first, last + 1, bucket)})
+
+
+def page_writes(mix: dict, bucket: int, max_seq: int, page: int,
+                ) -> List[Tuple[int, int]]:
+    """(prompt, answer) lengths, one pair for each number of pages a paged
+    admission can write for this mix.  The scheduler claims the pages of
+    prompt + answer and writes those of the padded prompt that it claimed,
+    so the count is min(pages of the padded width, pages of prompt +
+    answer); each count is a shape of its own.  Each pair has the shortest
+    answer that gives its count."""
+    p_min, p_max = int(mix["prompt"]["min"]), int(mix["prompt"]["max"])
+    o_min, o_max = int(mix["output"]["min"]), int(mix["output"]["max"])
+    by_width: dict = {}
+    for n in range(p_min, p_max + 1):
+        w = min(math.ceil(n / bucket) * bucket, max_seq)
+        lo, hi = by_width.get(w, (n, n))
+        by_width[w] = (min(lo, n), max(hi, n))
+    out, seen = [], set()
+    for w, (lo, hi) in sorted(by_width.items()):
+        cap = math.ceil(w / page)
+        for k in range(math.ceil((lo + o_min) / page),
+                       math.ceil((hi + o_max) / page) + 1):
+            if (w, min(k, cap)) in seen:
+                continue
+            seen.add((w, min(k, cap)))
+            total = max((k - 1) * page + 1, lo + o_min)
+            n = min(hi, total - o_min)
+            out.append((n, total - n))
+    return out

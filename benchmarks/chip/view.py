@@ -11,7 +11,10 @@ from benchmarks.chip.peaks import Peaks
 from benchmarks.chip.trace import Trace
 
 DECODE = "jit__decode_impl"        # the engine's scanned decode program
-PREFILL = "jit__prefill_impl"      # the engine's prefill program
+PREFILL = "jit__prefill_impl"      # the engine's prefill program (a mesh's too)
+MESH_DECODE = "jit_body"           # a mesh engine's decode: the jitted
+#                                    shard_map of ``body`` in
+#                                    ServeEngine._sharded_decode_sm
 
 
 @dataclasses.dataclass
@@ -22,6 +25,7 @@ class View:
     trace: Optional[Trace] = None
     costs: Optional[costs_mod.Totals] = None
     trace_end: Optional[float] = None      # host clock: the trace stopped
+    decode_program: str = DECODE       # MESH_DECODE on a mesh
 
     @property
     def until(self) -> float:

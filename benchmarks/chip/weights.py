@@ -16,7 +16,18 @@ same mixed policy for every seed and every run serves the same compiled
 programs.  The embedding (and an untied head) are pinned to 8 bits and
 never selected, so they are plain Gaussians.
 
-All of it is one jitted call on the device, in the served dtype.
+All of it is one jitted call on the device, in the served dtype.  Given
+a mesh (a configuration's ``"mesh": {"model": n}``), the call places each
+leaf as the tensor-parallel engine shards it: ``wq/wk/wv/gate/up`` on
+their output axis, ``wo/down`` on their input axis, the embedding and
+head on their vocabulary axis, the rest replicated.  The values are the
+same as on one device because each device computes every stack, its LSQ
+steps, the embedding and the head whole, as one device does, and keeps
+its slice: a step is a float sum whose order, on a TPU, follows the
+shape it runs over and what it is computed beside, so a step summed
+over one layer, or over the stack without the weights as an output,
+differs from one device's in the last bits.  A device therefore holds,
+besides its slice of the tree, the stack being made.
 """
 from __future__ import annotations
 
@@ -25,6 +36,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 ACT_STEP_4BIT = 2.0 / math.sqrt(2.0 ** 3 - 1)   # LSQ init for unit-variance input
 
@@ -75,11 +88,23 @@ def quantile_normal(key: jax.Array, shape, std: float, dtype) -> jax.Array:
     return (z * std).reshape(shape).astype(dtype)
 
 
-def _qdense(key, n_layers: int, d_in: int, d_out: int, dtype) -> dict:
+def _shard(mesh, x, *spec):
+    """``x`` placed on ``mesh`` by ``spec`` (replicated when it is empty);
+    as it is without a mesh."""
+    if mesh is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
+
+
+def _qdense(key, n_layers: int, d_in: int, d_out: int, dtype, mesh=None,
+            spec=()) -> dict:
+    """Stacked projections.  On a mesh each device makes the whole stack
+    and its steps as one device does, and keeps the slice ``spec``
+    places."""
     keys = jax.random.split(key, n_layers)
-    w = jax.vmap(lambda k: quantile_normal(k, (d_in, d_out),
-                                           d_in ** -0.5, dtype))(keys)
-    return {"w": w,
+    w = _shard(mesh, jax.vmap(lambda k: quantile_normal(
+        k, (d_in, d_out), d_in ** -0.5, dtype))(keys))
+    return {"w": _shard(mesh, w, None, *spec),
             "sw": jax.vmap(lambda x: _lsq_step(x, 4.0))(w),
             "sa": jnp.full((n_layers,), ACT_STEP_4BIT, jnp.float32)}
 
@@ -93,8 +118,12 @@ def _norm(key, cfg: dict, shape):
     raise ValueError(f"unknown norm {cfg['norm']!r}")
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _make(frozen: tuple, key: jax.Array) -> dict:
+COLUMN = (None, "model")   # a layer's output axis; the head's vocabulary
+ROW = ("model", None)      # a layer's input axis; the embedding's vocabulary
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _make(frozen: tuple, key: jax.Array, mesh=None) -> dict:
     cfg = dict(frozen)
     dt = jnp.dtype(cfg["dtype"])
     d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
@@ -102,26 +131,32 @@ def _make(frozen: tuple, key: jax.Array) -> dict:
                    cfg["head_dim"])
     f, v = cfg["intermediate_size"], cfg["vocab_size"]
     ks = iter(jax.random.split(key, 16))
-    embed = (jax.random.normal(next(ks), (v, d), jnp.float32) * 0.02
-             ).astype(dt)
+    embed = _shard(mesh, (jax.random.normal(next(ks), (v, d), jnp.float32)
+                          * 0.02).astype(dt))
+
+    def qdense(d_in, d_out, spec):
+        return _qdense(next(ks), L, d_in, d_out, dt, mesh, spec)
+
     block = {
         "norm1": _norm(next(ks), cfg, (L, d)),
-        "attn": {"wq": _qdense(next(ks), L, d, hq * hd, dt),
-                 "wk": _qdense(next(ks), L, d, hkv * hd, dt),
-                 "wv": _qdense(next(ks), L, d, hkv * hd, dt),
-                 "wo": _qdense(next(ks), L, hq * hd, d, dt)},
+        "attn": {"wq": qdense(d, hq * hd, COLUMN),
+                 "wk": qdense(d, hkv * hd, COLUMN),
+                 "wv": qdense(d, hkv * hd, COLUMN),
+                 "wo": qdense(hq * hd, d, ROW)},
         "norm2": _norm(next(ks), cfg, (L, d)),
-        "mlp": {"gate": _qdense(next(ks), L, d, f, dt),
-                "up": _qdense(next(ks), L, d, f, dt),
-                "down": _qdense(next(ks), L, f, d, dt)},
+        "mlp": {"gate": qdense(d, f, COLUMN),
+                "up": qdense(d, f, COLUMN),
+                "down": qdense(f, d, ROW)},
     }
-    params = {"embed": {"w": embed, "sw": _lsq_step(embed, 8.0)},
+    params = {"embed": {"w": _shard(mesh, embed, *ROW),
+                        "sw": _lsq_step(embed, 8.0)},
               "pat": {"p0": block},
               "final_norm": _norm(next(ks), cfg, (d,))}
     if not cfg["tie_word_embeddings"]:
-        head = (jax.random.normal(next(ks), (d, v), jnp.float32) * d ** -0.5
-                ).astype(dt)
-        params["head"] = {"w": head, "sw": _lsq_step(head, 8.0),
+        head = _shard(mesh, (jax.random.normal(next(ks), (d, v), jnp.float32)
+                             * d ** -0.5).astype(dt))
+        params["head"] = {"w": _shard(mesh, head, *COLUMN),
+                          "sw": _lsq_step(head, 8.0),
                           "sa": jnp.float32(cfg["head_act_step"])}
     return params
 
@@ -132,7 +167,8 @@ SHAPE_KEYS = ("hidden_size", "num_hidden_layers", "num_attention_heads",
               "dtype")
 
 
-def make(cfg: dict, seed: int) -> dict:
-    """The configuration's weights for ``seed``, on the default device."""
+def make(cfg: dict, seed: int, mesh=None) -> dict:
+    """The configuration's weights for ``seed``: on the default device, or
+    sharded over ``mesh`` (its ``"model"`` axis) with the same values."""
     frozen = tuple((k, cfg[k]) for k in SHAPE_KEYS)
-    return jax.block_until_ready(_make(frozen, key_for(seed)))
+    return jax.block_until_ready(_make(frozen, key_for(seed), mesh))
