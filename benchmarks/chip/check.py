@@ -7,7 +7,9 @@ slot's int8 cache rows in the first ``check.kv_layers`` layers are kept.
 Once the window has closed and the program's state is freed, each sampled
 request goes through the configuration's plain reference, computed in the
 type the configuration states and teacher-forced on the prompt and the
-served tokens.  Read:
+served tokens; a reference that reads its weights a layer at a time
+(``logits_by_layer``, for expert layers) takes every sampled request
+through a layer before it makes the next.  Read:
 
 * the cache rows the timed path wrote, prompt rows (the prefill) and
   decode rows (one written by every decode step), against the reference's
@@ -168,7 +170,24 @@ def _token_gaps(got, want, lo: int, hi: int, toks) -> np.ndarray:
     return np.stack([errs[:, toks == t].mean(1) for t in np.unique(toks)])
 
 
-def readings(cfg: dict, params, bits: Dict[str, List[int]],
+def _reference_runs(ref_mod, cfg: dict, params, bits, feds, acts, n_kv):
+    """Per sample, for each rounding in ``acts``, the reference's logits and
+    first-layer keys and values: a sample at a time over the whole tree,
+    or, where the reference reads its weights a layer at a time
+    (``logits_by_layer``), every sample through a layer before the next."""
+    if hasattr(ref_mod, "logits_by_layer"):
+        yield from ref_mod.logits_by_layer(cfg, params, bits, feds, acts,
+                                           n_kv)
+        return
+    width = cfg["max_seq"]
+    for fed, p_len in feds:
+        seq = np.zeros(width, np.int32)
+        seq[:len(fed)] = fed
+        yield [ref_mod.logits(cfg, params, bits, seq, p_len, act, n_kv)
+               for act in acts]
+
+
+def readings(cfg: dict, params, bits: Dict[str, list],
              samples: Sequence[Sample], control: Optional[Callable] = None,
              ) -> Dict[str, np.ndarray]:
     """Per served token its gap (``served``) and the reference's top-1
@@ -177,28 +196,27 @@ def readings(cfg: dict, params, bits: Dict[str, List[int]],
     int8 cache rows it left in the first ``check.kv_layers`` layers, prompt
     rows (``prefill_kv``) and decode rows (``decode_kv``).  With
     ``control``, the same readings of the control in the program's place
-    (``ctrl_``...)."""
+    (``ctrl_``...).  ``params``: the weights as the reference reads them
+    (``weights.for_reference``)."""
     ref_mod = reference_module(cfg["reference"])
-    width = cfg["max_seq"]
     n_kv = int(cfg["check"].get("kv_layers", 0))
+    acts = [rounding_for(cfg)] + ([control] if control is not None else [])
+    feds = [(list(prompt) + list(toks[:-1]), len(prompt))
+            for prompt, toks, _, _ in samples]
     acc: Dict[str, list] = {}
 
     def add(key, value):
         value = np.asarray(value, np.float64)
         acc.setdefault(key, []).append(value if value.ndim else value[None])
 
-    for prompt, toks, first_logits, snap in samples:
-        seq = np.zeros(width, np.int32)
-        fed = list(prompt) + list(toks[:-1])
-        seq[:len(fed)] = fed
+    runs = _reference_runs(ref_mod, cfg, params, bits, feds, acts, n_kv)
+    for (prompt, toks, first_logits, snap), got_runs in zip(samples, runs):
+        (ref, ref_kv), (other, other_kv) = (
+            got_runs if control is not None else (got_runs[0], (None, None)))
         p_len, n = len(prompt), len(toks)
-        ref, ref_kv = ref_mod.logits(cfg, params, bits, seq, p_len,
-                                     rounding_for(cfg), n_kv)
-        other, other_kv = (ref_mod.logits(cfg, params, bits, seq, p_len,
-                                          control, n_kv)
-                           if control is not None else (None, None))
-        pos = np.minimum(p_len - 1 + np.arange(width), width - 1)
-        tok = np.zeros(width, np.int32)
+        rows = ref.shape[0]
+        pos = np.minimum(p_len - 1 + np.arange(rows), rows - 1)
+        tok = np.zeros(rows, np.int32)
         tok[:n] = toks
         got = _read(ref, jnp.asarray(pos, jnp.int32), jnp.asarray(tok),
                     other, first_logits)
@@ -219,7 +237,7 @@ def readings(cfg: dict, params, bits: Dict[str, List[int]],
                     add(who + "decode_kv",
                         _token_gaps(got_rows, want, p_len, p_len + n - 1,
                                     toks[:-1]))
-        del ref, other, ref_kv, other_kv
+        del got_runs, ref, other, ref_kv, other_kv
     return {k: np.concatenate(v) for k, v in acc.items()}
 
 

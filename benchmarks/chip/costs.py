@@ -27,6 +27,23 @@ A served round, as the harness records it:
     step ``i`` of such a row processes the token at position ``ctx0 + i``
     and attends over ``ctx0 + i + 1`` rows.  Steps past the longest row's
     ``n`` are not work.
+
+An expert layer (``num_local_experts`` E held, ``num_experts_per_tok``
+k, expert width f) has q, k, v and o as a dense layer, the router (d, E)
+at its 8 bits, and three expert projections (gate, up: d -> f; down:
+f -> d).  In a step of M tokens the experts' work is
+
+  * FLOPs: 2 M k 3 d f -- each token through its k experts;
+  * bytes: the codes (and per-channel scales) of the experts that the
+    M k draws reach, E (1 - (1 - k/E)^M) of them in expectation, each at
+    its own bits, plus each routed row's input and output once in bf16.
+
+Each token picks k distinct experts of E; where routing spreads tokens
+evenly, one token misses a given expert with probability 1 - k/E and all
+M miss it with (1 - k/E)^M, and an expert no token reached is no work.
+The program computes every held expert over a buffer of capacity rows;
+what it does past the tokens routed reads low against the roofline.
+With no expert layers every count is the dense one.
 """
 from __future__ import annotations
 
@@ -49,15 +66,22 @@ class Dims:
     vocab: int
     cache_bits: int
     shards: int = 1             # chips of the "model" mesh axis
+    experts: int = 0            # experts held in each layer; 0: dense MLP
+    top_k: int = 0
+    f_expert: int = 0
 
     @classmethod
     def of(cls, cfg: dict) -> "Dims":
         return cls(d=cfg["hidden_size"], layers=cfg["num_hidden_layers"],
                    hq=cfg["num_attention_heads"],
                    hkv=cfg["num_key_value_heads"], hd=cfg["head_dim"],
-                   f=cfg["intermediate_size"], vocab=cfg["vocab_size"],
+                   f=cfg.get("intermediate_size", 0),
+                   vocab=cfg["vocab_size"],
                    cache_bits=cfg["engine"]["cache_bits"],
-                   shards=int(cfg.get("mesh", {}).get("model", 1)))
+                   shards=int(cfg.get("mesh", {}).get("model", 1)),
+                   experts=int(cfg.get("num_local_experts", 0)),
+                   top_k=int(cfg.get("num_experts_per_tok", 0)),
+                   f_expert=int(cfg.get("moe_intermediate_size", 0)))
 
     @property
     def hq_chip(self) -> int:
@@ -71,16 +95,21 @@ class Dims:
         """(policy slot, K, N) of each packed projection of one layer, one
         chip's share."""
         q, kv = self.hq_chip * self.hd, self.hkv_chip * self.hd
+        attn = [("attn_qkv", self.d, q), ("attn_qkv", self.d, kv),
+                ("attn_qkv", self.d, kv), ("attn_wo", q, self.d)]
+        if self.experts:            # the expert banks: expert_matmuls
+            return attn + [("moe_router", self.d, self.experts)]
         f = self.f // self.shards
-        return [("attn_qkv", self.d, q), ("attn_qkv", self.d, kv),
-                ("attn_qkv", self.d, kv), ("attn_wo", q, self.d),
-                ("mlp_gateup", self.d, f), ("mlp_gateup", self.d, f),
-                ("mlp_down", f, self.d)]
+        return attn + [("mlp_gateup", self.d, f), ("mlp_gateup", self.d, f),
+                       ("mlp_down", f, self.d)]
 
     @property
     def proj_params(self) -> int:
-        """Weights of the projections of all layers, one chip's share."""
-        return self.layers * sum(k * n for _, k, n in self.projections())
+        """Weights of the projections of all layers that every token
+        passes through (with expert layers, the k experts' too), one chip's
+        share."""
+        every = self.layers * sum(k * n for _, k, n in self.projections())
+        return every + self.layers * self.top_k * 3 * self.d * self.f_expert
 
 
 def quant_matmul(m: int, k: int, n: int, bits: int) -> Tuple[float, float]:
@@ -88,6 +117,23 @@ def quant_matmul(m: int, k: int, n: int, bits: int) -> Tuple[float, float]:
     flops = 2.0 * m * k * n
     data = k * n * bits / 8 + n * F32 + m * k * BF16 + m * n * BF16
     return flops, data
+
+
+def expert_matmuls(m: int, dims: Dims, gateup_bits: List[int],
+                   down_bits: List[int]) -> List[Tuple[float, float]]:
+    """(FLOPs, bytes) of one expert layer's gate, up and down projections
+    for ``m`` tokens, with each expert's bits (the module's rule)."""
+    d, f, k = dims.d, dims.f_expert, dims.top_k
+    reach = 1.0 - (1.0 - k / dims.experts) ** m
+    rows = m * k
+
+    def one(k_in, n_out, bits):
+        codes = sum(k_in * n_out * b / 8 + n_out * F32 for b in bits)
+        return (2.0 * rows * k_in * n_out,
+                reach * codes + rows * (k_in + n_out) * BF16)
+
+    return [one(d, f, gateup_bits), one(d, f, gateup_bits),
+            one(f, d, down_bits)]
 
 
 def decode_attention(ctxs: Iterable[int], dims: Dims) -> Tuple[float, float]:
@@ -106,8 +152,9 @@ def decode_attention(ctxs: Iterable[int], dims: Dims) -> Tuple[float, float]:
 
 def token_flops(dims: Dims, ctx: int) -> float:
     """Model FLOPs of one decoded token attending ``ctx`` rows: the
-    projections, the LM head and attention's two products (one chip's
-    share; the replicated head in full)."""
+    projections (k experts of an expert layer), the LM head and
+    attention's two products (one chip's share; the replicated head in
+    full)."""
     return (2.0 * dims.proj_params + 2.0 * dims.d * dims.vocab
             + 4.0 * dims.layers * dims.hq_chip * dims.hd * ctx)
 
@@ -131,9 +178,11 @@ def totals(rounds: Iterable[dict], dims: Dims,
            bits: Dict[str, List[int]], peak_flops: float,
            peak_bytes: float) -> Totals:
     """Least kernel times and model FLOPs of the served rounds.  ``bits``:
-    policy slot -> bits per layer."""
+    policy slot -> bits per layer (per layer, a list over the experts of
+    an expert slot)."""
     layers_at = {slot: collections.Counter(per_layer)
-                 for slot, per_layer in bits.items()}
+                 for slot, per_layer in bits.items()
+                 if not slot.startswith("moe_") or slot == "moe_router"}
 
     def qmm_least(m: int) -> float:
         t = 0.0
@@ -141,6 +190,10 @@ def totals(rounds: Iterable[dict], dims: Dims,
             for b, count in layers_at[slot].items():
                 t += count * least_time(*quant_matmul(m, k, n, b),
                                         peak_flops, peak_bytes)
+        if dims.experts:
+            for gateup, down in zip(bits["moe_gateup"], bits["moe_down"]):
+                for work in expert_matmuls(m, dims, gateup, down):
+                    t += least_time(*work, peak_flops, peak_bytes)
         return t
 
     out = Totals()

@@ -132,6 +132,14 @@ def main(argv=None) -> int:
                     checked_fault={**FAULTS, **MESH_FAULTS}.get(args.fault))
 
 
+def ffn_of(cfg: dict) -> str:
+    if "num_local_experts" in cfg:
+        return (f"{cfg['num_local_experts']} experts, top "
+                f"{cfg['num_experts_per_tok']}, expert d_ff "
+                f"{cfg['moe_intermediate_size']}")
+    return f"d_ff {cfg['intermediate_size']}"
+
+
 def run_cell(cell, args, devices, *, checked_fault=None) -> int:
     """Everything after the look for a chip.  ``checked_fault`` lets a test
     break the timed path underneath (benchmarks/chip/tests)."""
@@ -147,7 +155,7 @@ def run_cell(cell, args, devices, *, checked_fault=None) -> int:
     log(f"cell {cell.name}: config {cell.config_name} (model {cfg['model']},"
         f" {cfg['num_hidden_layers']} layers, d {cfg['hidden_size']}, "
         f"{cfg['num_attention_heads']}/{cfg['num_key_value_heads']} heads x "
-        f"{cfg['head_dim']}, d_ff {cfg['intermediate_size']}, vocab "
+        f"{cfg['head_dim']}, {ffn_of(cfg)}, vocab "
         f"{cfg['vocab_size']}), {cfg['n_slots']} slots x {cfg['max_seq']}, "
         f"{cfg['engine'].get('cache_layout', 'contiguous')} cache, mesh "
         f"{cfg.get('mesh')}, traffic {cell.traffic_name}, seed {args.seed}, "
@@ -268,7 +276,7 @@ def run_cell(cell, args, devices, *, checked_fault=None) -> int:
     del loop, sched, engine, run, v, done
     gc.collect()
     t = time.perf_counter()
-    params = weights.make(cfg, args.seed, mesh)
+    params = weights.for_reference(cfg, args.seed, mesh)
     control = check.control_for(cfg) if args.control else None
     got = check.readings(cfg, params, bits, samples, control=control)
     del params

@@ -5,7 +5,9 @@ program's own pipeline: EAGL gains (the Pallas histogram), the knapsack at
 the configuration's budget, ``pack_params``, and a ``ServeEngine`` behind a
 ``ContinuousBatchingScheduler``.  A configuration that states
 ``"mesh": {"model": n}`` is served tensor-parallel over the cell's ``n``
-chips: the weights are made sharded and the engine gets the mesh.
+chips: the weights are made sharded and the engine gets the mesh.  One
+with expert layers never holds its whole bf16 tree: EAGL reads each
+layer as it is made, and packing packs a layer at a time.
 
 ``drive`` is the open-loop client.  The scheduler only drains a queue it
 was given, so the loop here plays its ``run()`` one round at a time and
@@ -31,23 +33,59 @@ from benchmarks.chip import e2e, traffic, weights
 clock = time.perf_counter
 
 
+# Keys a configuration file may hold.  Anything else -- shared experts, a
+# prefix of dense layers, another attention -- is a block the harness does
+# not describe, and is refused.
+KNOWN_KEYS = frozenset((
+    "model", "source", "reduced", "assumed", "hidden_size",
+    "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
+    "head_dim", "intermediate_size", "vocab_size", "max_position_embeddings",
+    "rope_theta", "norm", "norm_eps", "tie_word_embeddings", "hidden_act",
+    "dtype", "head_act_step", "reference", "policy", "engine", "n_slots",
+    "max_seq", "prompt_bucket", "check", "mesh", "arch")
+    + weights.EXPERT_KEYS)
+
+
 def arch_config(cfg: dict):
     """The program's ArchConfig for a configuration file: the named model
-    with every size the file states."""
+    with every size the file states.  Expert keys (``num_local_experts``,
+    the experts held here; ``num_experts_per_tok``;
+    ``moe_intermediate_size``) make the block GQA + MoE; an ``arch`` object
+    is handed to ``ArchConfig.replace`` as it stands."""
     from repro import configs
+    from repro.models.common import BlockDef
+    unknown = sorted(set(cfg) - KNOWN_KEYS)
+    if unknown:
+        raise ValueError(
+            f"{cfg['model']}: unknown configuration keys {unknown}: a "
+            "configuration file describes one repeated GQA block with a "
+            "SwiGLU MLP or expert layers, with no prefix of dense layers "
+            "and no shared experts")
     base = configs.get_config(cfg["model"])
     if len(base.pattern) != 1 or base.prefix:
         raise ValueError(f"{cfg['model']}: only one repeated block is "
-                         "described by a configuration file")
+                         "described by a configuration file, not a prefix "
+                         "of dense layers")
     dt = jnp.dtype(cfg["dtype"])
-    return base.replace(
+    kw = dict(
         d_model=cfg["hidden_size"], n_repeats=cfg["num_hidden_layers"],
         n_heads=cfg["num_attention_heads"],
         n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
+        vocab=cfg["vocab_size"],
         norm=cfg["norm"], rope_base=float(cfg["rope_theta"]),
         tie_embeddings=cfg["tie_word_embeddings"],
         param_dtype=dt, compute_dtype=dt)
+    if weights.has_experts(cfg):
+        kw.update(n_experts=cfg["num_local_experts"],
+                  top_k=cfg["num_experts_per_tok"],
+                  d_ff=cfg["moe_intermediate_size"],
+                  pattern=(BlockDef("gqa", "moe"),))
+    elif base.pattern[0].ffn == "moe":
+        raise ValueError(f"{cfg['model']}: an expert model needs "
+                         f"{list(weights.EXPERT_KEYS)}")
+    else:
+        kw["d_ff"] = cfg["intermediate_size"]
+    return base.replace(**kw).replace(**cfg.get("arch", {}))
 
 
 def mesh_for(cfg: dict, devices):
@@ -66,11 +104,13 @@ def mesh_for(cfg: dict, devices):
     return make_mesh((n,), ("model",), devices=devices)
 
 
-def select_policy(arch, params, pol: dict, device=None):
+def select_policy(arch, fetch, pol: dict, device=None):
     """EAGL gains -> knapsack at the budget; returns the mixed policy and
-    its bits per layer for each projection group.  ``device``: where each
-    tensor is brought whole for its histogram (a Pallas kernel is not
-    partitioned over a mesh); None leaves the tensors where they are."""
+    its bits per layer for each projection group (per layer, a list over
+    the experts of an expert group).  ``fetch(unit, path)`` gives a unit's
+    tensor and LSQ step; ``device``: where each tensor is brought whole for
+    its histogram (a Pallas kernel is not partitioned over a mesh); None
+    leaves the tensors where they are."""
     from repro.core import knapsack
     from repro.core.metrics import eagl
     from repro.models import transformer as tf
@@ -78,43 +118,95 @@ def select_policy(arch, params, pol: dict, device=None):
         raise ValueError(f"unknown policy metric {pol['metric']!r}")
     policy = tf.build_policy(arch, b_hi=pol["b_hi"], b_lo=pol["b_lo"])
 
-    def fetch(u, t):
-        got = tf.fetch_unit_tensor(params, u, t)
+    def placed(u, t):
+        got = fetch(u, t)
         return got if device is None else jax.device_put(got, device)
 
-    gains = eagl.eagl_gains(policy, fetch, impl="auto")
+    gains = eagl.eagl_gains(policy, placed, impl="auto")
     mixed = policy.apply_selection(
         knapsack.select_for_budget(policy, gains, pol["budget_frac"]).take)
-    bits = {slot: [int(b) for b in arr]
+    bits = {slot: arr.astype(int).tolist()
             for slot, arr in mixed.as_arrays()["pat0"].items()}
     return mixed, bits
+
+
+def pack_by_layer(src: weights.Source, policy_arrays, arch, cache_bits):
+    """``pack_params``' bucketed layout, made a layer at a time: each layer
+    of ``src`` packed as ``pack_params`` packs it and written into its
+    bucket's stack, so no more than one layer's weights are held."""
+    from repro.core.policy import bucket_plan
+    from repro.models import transformer as tf
+    from repro.models.layout import LayerBuckets
+    from repro.serve import packing
+    slot_of = tf._slot_index(arch)
+    out = packing.pack_params(src.edges(), policy_arrays, arch,
+                              cache_bits=cache_bits)
+    plan = bucket_plan(policy_arrays, cache_bits, n_layers=arch.n_repeats)
+    buckets, start = [], 0
+    for size in plan.sizes:
+        stack = None
+        for j in range(size):
+            layer = packing._walk({"p0": src.layer(start + j)}, ("pat",),
+                                  start + j, slot_of, policy_arrays)
+            src.release()
+            if stack is None:
+                stack = jax.tree.map(
+                    lambda x: jnp.zeros((size,) + x.shape, x.dtype), layer)
+            stack = weights.put(stack, layer, j)
+            del layer
+        buckets.append(jax.block_until_ready(stack))
+        start += size
+    out["pat"] = LayerBuckets(tuple(buckets), plan.sizes)
+    return out
 
 
 def build(cfg: dict, seed: int, log, devices=None):
     """Weights from ``seed`` -> policy -> packed engine and scheduler, on
     the cell's ``devices`` (a mesh over them when the configuration states
     one).  The bf16 weights are dropped before returning: only what a
-    deployment holds stays resident."""
+    deployment holds stays resident.  With expert layers the weights are
+    made a layer at a time (``weights.Source``), once for EAGL and once for
+    packing, and never whole."""
+    from repro.models import transformer as tf
     from repro.parallel.context import local_context
     from repro.serve import (ContinuousBatchingScheduler, EngineSpec,
                              ServeEngine, pack_params)
     arch = arch_config(cfg)
     mesh = mesh_for(cfg, devices)
+    eng_cfg = cfg["engine"]
+    experts = weights.has_experts(cfg)
+    if experts and mesh is not None:
+        raise ValueError("expert layers are served on one chip: the "
+                         "program's packed expert banks have no mesh form")
+    if experts:
+        src = weights.Source(cfg, seed)
+        fetch = src.fetch
+    else:
+        t = clock()
+        params = weights.make(cfg, seed, mesh)
+        log(f"[set-up] weights {clock() - t:.2f} s")
+        fetch = functools.partial(tf.fetch_unit_tensor, params)
     t = clock()
-    params = weights.make(cfg, seed, mesh)
-    log(f"[set-up] weights {clock() - t:.2f} s")
-    t = clock()
-    mixed, bits = select_policy(arch, params, cfg["policy"],
+    mixed, bits = select_policy(arch, fetch, cfg["policy"],
                                 None if mesh is None else devices[0])
     log(f"[set-up] EAGL + knapsack {clock() - t:.2f} s")
     for slot, per_layer in bits.items():
         log(f"policy {slot}: per-layer bits {per_layer}")
-    eng_cfg = cfg["engine"]
     t = clock()
-    packed = jax.block_until_ready(pack_params(
-        params, mixed.as_arrays(), arch, cache_bits=eng_cfg["cache_bits"]))
-    log(f"[set-up] pack {clock() - t:.2f} s")
-    del params
+    if experts:
+        src.release()
+        made = src.seconds
+        packed = pack_by_layer(src, mixed.as_arrays(), arch,
+                               eng_cfg["cache_bits"])
+        log(f"[set-up] pack {clock() - t:.2f} s")
+        log(f"[set-up] of these, making the weights a layer at a time: "
+            f"{made:.2f} s in EAGL, {src.seconds - made:.2f} s in packing")
+    else:
+        packed = jax.block_until_ready(pack_params(
+            params, mixed.as_arrays(), arch,
+            cache_bits=eng_cfg["cache_bits"]))
+        log(f"[set-up] pack {clock() - t:.2f} s")
+        del params
     engine = ServeEngine(
         cfg=arch, params=packed,
         policy_arrays=jax.tree.map(jnp.asarray, mixed.as_arrays()),

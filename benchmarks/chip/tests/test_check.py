@@ -18,11 +18,11 @@ import types
 import jax
 import pytest
 
-from benchmarks.chip import peaks, run
+from benchmarks.chip import faults, peaks, run
 from benchmarks.chip import workload
-from benchmarks.chip.faults import FAULTS
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+FAULTS = faults.for_config(workload.load_json(DATA, "configs", "tiny"))
 
 
 @pytest.fixture(scope="module")

@@ -194,3 +194,51 @@ def test_one_chips_share_times_four_less_the_replicated_part_is_the_whole():
     assert (t4.decode_tokens, t4.decode_steps, t4.prefill_tokens) == (
         t1.decode_tokens, t1.decode_steps, t1.prefill_tokens)
     assert t4.attn_least_s == pytest.approx(t1.attn_least_s / 4, rel=1e-12)
+
+
+DBRX = {"hidden_size": 6144, "num_hidden_layers": 4,
+        "num_attention_heads": 48, "num_key_value_heads": 8, "head_dim": 128,
+        "num_local_experts": 16, "num_experts_per_tok": 4,
+        "moe_intermediate_size": 10752, "vocab_size": 100352,
+        "engine": {"cache_bits": 8}}
+
+
+def test_an_expert_layers_costs_follow_the_stated_rule():
+    dims = costs.Dims.of(DBRX)
+    d, f, e, k = 6144, 10752, 16, 4
+    q, kv = 48 * 128, 8 * 128
+    assert dims.projections() == [
+        ("attn_qkv", d, q), ("attn_qkv", d, kv), ("attn_qkv", d, kv),
+        ("attn_wo", q, d), ("moe_router", d, e)]
+    # every token passes through q, k, v, o, the router and k experts
+    layer = d * q + 2 * d * kv + q * d + d * e + k * 3 * d * f
+    assert dims.proj_params == 4 * layer
+    assert costs.token_flops(dims, 100) == (2 * 4 * layer + 2 * d * 100352
+                                            + 4 * 4 * 48 * 128 * 100)
+    gateup, down = [4] * 10 + [2] * 6, [2] * 16
+    # one token reaches k of E experts; 32 tokens nearly all of them
+    for m, reach in ((1, k / e), (32, 1 - (1 - k / e) ** 32)):
+        work = costs.expert_matmuls(m, dims, gateup, down)
+        codes_gu = sum(d * f * b / 8 + f * 4 for b in gateup)
+        codes_dn = sum(f * d * b / 8 + d * 4 for b in down)
+        rows = m * k * (d + f) * 2
+        assert work == [(2 * m * k * d * f, reach * codes_gu + rows),
+                        (2 * m * k * d * f, reach * codes_gu + rows),
+                        (2 * m * k * f * d, reach * codes_dn + rows)]
+        assert sum(fl for fl, _ in work) == 2 * m * k * 3 * d * f
+    assert reach == pytest.approx(16 * (1 - 0.75 ** 32) / 16)
+    bits = {"attn_qkv": [4] * 4, "attn_wo": [4] * 4, "moe_router": [8] * 4,
+            "moe_gateup": [gateup] * 4, "moe_down": [down] * 4}
+    rounds = [{"kind": "decode", "steps": 2, "rows": [[10, 2], [20, 1]]}]
+    t = costs.totals(rounds, dims, bits, 197e12, 819e9)
+    want = 0.0
+    for m in (2, 1):                     # two rows, then one
+        for _, kk, n in dims.projections():
+            b = 8 if n == e else 4
+            want += 4 * costs.least_time(*costs.quant_matmul(m, kk, n, b),
+                                         197e12, 819e9)
+        want += 4 * sum(costs.least_time(*w, 197e12, 819e9) for w in
+                        costs.expert_matmuls(m, dims, gateup, down))
+    assert t.qmm_least_s == pytest.approx(want, rel=1e-12)
+    assert t.decode_flops == sum(costs.token_flops(dims, c)
+                                 for c in (11, 12, 21))
